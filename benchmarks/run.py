@@ -27,6 +27,7 @@ from repro.core.latency import (H100_NODE, LLAMA_405B, LLAMA_8B, RTX_4090,
 from repro.core.simulated import (ScriptedRemote, SimulatedLocal,
                                   context_factor, steps_factor)
 from repro.core.tasks import make_dataset, score_answer
+from repro.launch import compile_cache
 
 CM = CostModel()
 ROWS: List[str] = []
@@ -790,6 +791,7 @@ def main() -> None:
     ap.add_argument("--only", default=None, choices=sorted(BENCHMARKS))
     ap.add_argument("--tasks", type=int, default=12)
     args = ap.parse_args()
+    compile_cache.configure()
     print("name,us_per_call,derived")
     for name, fn in BENCHMARKS.items():
         if args.only and name != args.only:

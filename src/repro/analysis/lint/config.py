@@ -5,7 +5,7 @@ today the R6 VMEM budget and the worst-case symbolic dims its abstract
 evaluator assumes for shape-derived block dimensions::
 
     [vmem]
-    budget_bytes = 16777216      # 16 MiB per TensorCore
+    budget_bytes = 67108864      # the kernels' 64 MiB scoped limit
     assumed_input_bytes = 4      # dtype width assumed for i/o blocks
 
     [vmem.dims]
@@ -13,22 +13,23 @@ evaluator assumes for shape-derived block dimensions::
     ps = 128                     # page size (paged-pool KV block)
     group = 8                    # q heads per kv head (GQA group)
 
-Parsing uses :mod:`tomllib` where available (py >= 3.11) and falls back
-to a restricted line-based parser (sections, ``key = int/float/bool/
-"str"``, ``#`` comments) so the linter runs on 3.10 with zero deps.
+Parsed with :mod:`tomllib`.
 """
 from __future__ import annotations
 
 import dataclasses
+import tomllib
 from pathlib import Path
 from typing import Dict, Optional
 
-DEFAULT_VMEM_BUDGET = 16 * 1024 * 1024   # ~16 MiB VMEM per TensorCore
+DEFAULT_VMEM_BUDGET = 64 * 1024 * 1024   # kernels' scoped-VMEM limit
 DEFAULT_DIMS = {
     "hd": 128,     # head dim (MXU-aligned worst case)
     "ps": 128,     # page size: pool KV block = one page
     "group": 8,    # GQA group width (q heads per kv head)
-    "hkv": 8,      # kv head count (unused by current kernels' blocks)
+    "hkv": 8,      # kv head count (every KV block holds all kv heads)
+    "h": 64,       # q head count (prefill q/out blocks hold all heads)
+    "block_k": 256,  # decode KV tile
 }
 
 
@@ -40,52 +41,12 @@ class LintConfig:
         default_factory=lambda: dict(DEFAULT_DIMS))
 
 
-def _parse_toml_min(text: str) -> dict:
-    """Restricted TOML: ``[a.b]`` tables and scalar ``key = value`` lines
-    (int, float, bool, quoted string).  Enough for repro-lint.toml."""
-    out: dict = {}
-    table = out
-    for raw in text.splitlines():
-        line = raw.strip()
-        if not line or line.startswith("#"):
-            continue
-        if line.startswith("[") and line.endswith("]"):
-            table = out
-            for part in line[1:-1].strip().split("."):
-                table = table.setdefault(part.strip(), {})
-            continue
-        if "=" not in line:
-            raise ValueError(f"unparseable config line: {raw!r}")
-        key, _, val = line.partition("=")
-        val = val.split("#", 1)[0].strip()
-        key = key.strip()
-        if val.startswith(("'", '"')) and val.endswith(val[0]) \
-                and len(val) >= 2:
-            table[key] = val[1:-1]
-        elif val in ("true", "false"):
-            table[key] = val == "true"
-        else:
-            try:
-                table[key] = int(val)
-            except ValueError:
-                table[key] = float(val)
-    return out
-
-
-def _parse_toml(text: str) -> dict:
-    try:
-        import tomllib
-    except ModuleNotFoundError:
-        return _parse_toml_min(text)
-    return tomllib.loads(text)
-
-
 def load_config(path: Optional[Path]) -> LintConfig:
     """Load ``repro-lint.toml``; a missing file yields the defaults."""
     cfg = LintConfig()
     if path is None or not Path(path).exists():
         return cfg
-    data = _parse_toml(Path(path).read_text())
+    data = tomllib.loads(Path(path).read_text())
     vmem = data.get("vmem", {})
     if "budget_bytes" in vmem:
         cfg.vmem_budget_bytes = int(vmem["budget_bytes"])

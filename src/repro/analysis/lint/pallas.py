@@ -372,6 +372,8 @@ class VmemBudgetRule(PallasKernelRule):
                   depth: int = 6) -> Optional[int]:
         if depth <= 0:
             return None
+        if isinstance(expr, ast.Constant) and expr.value is None:
+            return 1        # squeezed block dim
         v = _const_int(expr)
         if v is not None:
             return v

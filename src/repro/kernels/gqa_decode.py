@@ -4,12 +4,14 @@ The MinionS decode hot path: many parallel local jobs each decode one token
 per step against their own chunk's KV cache.  Grouped-query heads are
 processed together so the MXU sees a (q_per_kv × block_k) matmul per tile
 instead of q_per_kv separate vector dots, and the KV cache is streamed
-HBM→VMEM once per kv head (not once per q head — no materialised
-``repeat_kv``).
+HBM→VMEM once (not once per q head — no materialised ``repeat_kv``).
 
-Grid: (batch, kv_heads, kv_blocks); kv innermost with VMEM online-softmax
-scratch.  ``valid_len`` (B,) masks unwritten ring-buffer slots and is
-delivered via scalar prefetch so fully-dead KV tiles are skipped.
+Grid: (batch, kv_blocks); kv innermost with VMEM online-softmax scratch.
+One grid step owns the (block_k, Hkv, hd) tile of every kv head — the
+cache's own (Hkv, hd) minor dims, which is what Mosaic accepts — and
+loops over the heads inside.  ``valid_len`` (B,) masks unwritten
+ring-buffer slots and is delivered via scalar prefetch so fully-dead KV
+tiles are skipped.
 """
 from __future__ import annotations
 
@@ -21,23 +23,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from . import flash
+
 LANES = 128
 
 
-def _kernel(start_ref, valid_ref, q_ref, k_ref, v_ref, out_ref,
+def _kernel(first_ref, valid_ref, q_ref, k_ref, v_ref, out_ref,
             acc_ref, m_ref, l_ref, *, block_k: int, sm_scale: float,
-            num_kv_blocks: int, group: int):
+            num_kv_blocks: int, paged: bool):
+    """``first_ref`` is the per-row start slot (dense) or the page table
+    (paged, where every row starts at slot 0)."""
     bb = pl.program_id(0)
-    kj = pl.program_id(2)
+    kj = pl.program_id(1)
+    hkv, group, _ = q_ref.shape
 
     @pl.when(kj == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        flash.init(acc_ref, m_ref, l_ref)
 
-    start = start_ref[bb]
+    start = 0 if paged else first_ref[bb]
     valid = valid_ref[bb]
     # skip tiles entirely before the row's first valid slot (left-padding)
     # or entirely at/after its write frontier
@@ -46,153 +50,79 @@ def _kernel(start_ref, valid_ref, q_ref, k_ref, v_ref, out_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * sm_scale  # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # (bk, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G, bk)
         kpos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (q.shape[0], block_k), 1)
-        s = jnp.where((kpos >= start) & (kpos < valid), s, NEG_INF)
-
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:, 0] = m_new
-        l_ref[:, 0] = l_new
+            jnp.int32, (group, block_k), 1)
+        mask = (kpos >= start) & (kpos < valid)
+        for h in range(hkv):
+            q = q_ref[h].astype(jnp.float32) * sm_scale        # (G, hd)
+            flash.update(h, q, k_ref[:, h, :].astype(jnp.float32),
+                         v_ref[:, h, :].astype(jnp.float32), mask,
+                         acc_ref, m_ref, l_ref)
 
     @pl.when(kj == num_kv_blocks - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        out_ref[0, 0, :, :] = (acc_ref[...] / l[:, None]).astype(out_ref.dtype)
+        for h in range(hkv):
+            out_ref[h] = flash.result(h, acc_ref, l_ref).astype(out_ref.dtype)
+
+
+def _decode_call(first, valid_len, q, k, v, *, block_k: int, n_blocks: int,
+                 kv_index, paged: bool, interpret: bool):
+    b, h, hd = q.shape
+    hkv = k.shape[2]
+    assert h % hkv == 0, (h, hkv)
+    group = h // hkv
+    # (B, H, hd) -> (B, Hkv, G, hd) so each kv head's q group is one slab
+    qg = q.reshape(b, hkv, group, hd)
+    kernel = functools.partial(_kernel, block_k=block_k,
+                               sm_scale=1.0 / math.sqrt(hd),
+                               num_kv_blocks=n_blocks, paged=paged)
+    row = pl.BlockSpec((None, hkv, group, hd),
+                       lambda bb, kj, first, valid: (bb, 0, 0, 0))
+    kv = pl.BlockSpec((None, block_k, hkv, hd), kv_index)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, n_blocks),
+        in_specs=[row, kv, kv],
+        out_specs=row,
+        scratch_shapes=[pltpu.VMEM((hkv, group, hd), jnp.float32),
+                        pltpu.VMEM((hkv, group, LANES), jnp.float32),
+                        pltpu.VMEM((hkv, group, LANES), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, hd), q.dtype),
+        compiler_params=flash.compiler_params("parallel", "arbitrary"),
+        interpret=interpret,
+    )(first, valid_len, qg, k, v)
+    return out.reshape(b, h, hd)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def gqa_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                          v_cache: jnp.ndarray, valid_len: jnp.ndarray,
-                         start: jnp.ndarray = None, *,
-                         block_k: int = 256,
-                         interpret: bool = True) -> jnp.ndarray:
-    """q: (B, H, hd); caches: (B, L, Hkv, hd); valid_len: (B,) int32.
+                         start: jnp.ndarray, *, block_k: int,
+                         interpret: bool) -> jnp.ndarray:
+    """q: (B, H, hd); caches: (B, L, Hkv, hd); valid_len, start: (B,) int32.
 
-    ``start`` (B,) int32 marks the first valid cache slot per row — slots
-    in [start, valid_len) attend, everything else (left-padding from the
-    engine's ragged batches, unwritten tail) is masked and fully-dead KV
-    tiles are skipped.  Defaults to 0 (all slots below valid_len valid).
-
-    Returns (B, H, hd).  L must be a multiple of block_k (ops.py pads).
+    Slots in [start, valid_len) attend; everything else (left-padding from
+    the engine's ragged batches, unwritten tail) is masked and fully-dead
+    KV tiles are skipped.  Returns (B, H, hd).  L must be a multiple of
+    block_k (ops.py pads).
     """
-    b, h, hd = q.shape
-    _, l, hkv, _ = k_cache.shape
-    assert h % hkv == 0
-    group = h // hkv
+    l = k_cache.shape[1]
     assert l % block_k == 0, (l, block_k)
-    nk = l // block_k
-    sm_scale = 1.0 / math.sqrt(hd)
-    if start is None:
-        start = jnp.zeros((b,), jnp.int32)
-
-    # (B, H, hd) -> (B, Hkv, G, hd) so one grid step owns a whole q group
-    qg = q.reshape(b, hkv, group, hd)
-
-    kernel = functools.partial(_kernel, block_k=block_k, sm_scale=sm_scale,
-                               num_kv_blocks=nk, group=group)
-
-    compiler_params = None
-    cp_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-    if cp_cls is not None:
-        compiler_params = cp_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, hd),
-                         lambda bb, kh, kj, start, valid: (bb, kh, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bb, kh, kj, start, valid: (bb, kj, kh, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bb, kh, kj, start, valid: (bb, kj, kh, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, hd),
-                               lambda bb, kh, kj, start, valid:
-                               (bb, kh, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((group, hd), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-        ],
-    )
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, hd), q.dtype),
-        compiler_params=compiler_params,
-        interpret=interpret,
-    )(start, valid_len, qg, k_cache, v_cache)
-    return out.reshape(b, h, hd)
-
-
-def _paged_kernel(pt_ref, valid_ref, q_ref, k_ref, v_ref, out_ref,
-                  acc_ref, m_ref, l_ref, *, page_size: int, sm_scale: float,
-                  num_kv_pages: int, group: int):
-    bb = pl.program_id(0)
-    kj = pl.program_id(2)
-
-    @pl.when(kj == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    valid = valid_ref[bb]
-    # skip pages entirely at/after the row's write frontier (padded page
-    # table entries point at the null page and are always dead here)
-    live = kj * page_size < valid
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * sm_scale  # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # (ps, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G, ps)
-        kpos = kj * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (q.shape[0], page_size), 1)
-        s = jnp.where(kpos < valid, s, NEG_INF)
-
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:, 0] = m_new
-        l_ref[:, 0] = l_new
-
-    @pl.when(kj == num_kv_pages - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        out_ref[0, 0, :, :] = (acc_ref[...] / l[:, None]).astype(out_ref.dtype)
+    return _decode_call(start, valid_len, q, k_cache, v_cache,
+                        block_k=block_k, n_blocks=l // block_k,
+                        kv_index=lambda bb, kj, start, valid: (bb, kj, 0, 0),
+                        paged=False, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_gqa_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                                v_pool: jnp.ndarray, page_table: jnp.ndarray,
                                valid_len: jnp.ndarray, *,
-                               interpret: bool = True) -> jnp.ndarray:
+                               interpret: bool) -> jnp.ndarray:
     """Paged variant: K/V live in a shared page pool and each row gathers
     its cache through a page table delivered via scalar prefetch — the K/V
     index maps translate the grid's page coordinate to a physical page, so
@@ -204,54 +134,9 @@ def paged_gqa_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     global position j, positions >= valid_len are masked.  The KV block is
     one page (block_k == page_size).  Returns (B, H, hd).
     """
-    b, h, hd = q.shape
-    _, ps, hkv, _ = k_pool.shape
-    assert h % hkv == 0
-    group = h // hkv
-    p_max = page_table.shape[1]
-    sm_scale = 1.0 / math.sqrt(hd)
-
-    qg = q.reshape(b, hkv, group, hd)
-
-    kernel = functools.partial(_paged_kernel, page_size=ps,
-                               sm_scale=sm_scale, num_kv_pages=p_max,
-                               group=group)
-
-    compiler_params = None
-    cp_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-    if cp_cls is not None:
-        compiler_params = cp_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, p_max),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, hd),
-                         lambda bb, kh, kj, pt, valid: (bb, kh, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda bb, kh, kj, pt, valid:
-                         (pt[bb, kj], 0, kh, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda bb, kh, kj, pt, valid:
-                         (pt[bb, kj], 0, kh, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, hd),
-                               lambda bb, kh, kj, pt, valid:
-                               (bb, kh, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((group, hd), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-        ],
-    )
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, hd), q.dtype),
-        compiler_params=compiler_params,
-        interpret=interpret,
-    )(page_table, valid_len, qg, k_pool, v_pool)
-    return out.reshape(b, h, hd)
+    return _decode_call(page_table, valid_len, q, k_pool, v_pool,
+                        block_k=k_pool.shape[1],
+                        n_blocks=page_table.shape[1],
+                        kv_index=lambda bb, kj, pt, valid:
+                        (pt[bb, kj], 0, 0, 0),
+                        paged=True, interpret=interpret)

@@ -1,7 +1,11 @@
 """Serving launcher: batched generation with the inference engine.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b --smoke \
+    PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b \
         [--checkpoint out/ckpt.npz] --prompts "hello" "world"
+
+``--smoke`` (the default) serves the family's reduced smoke config;
+``--no-smoke`` serves the registry config at its published widths and
+vocabulary (random weights from ``--seed`` unless ``--checkpoint``).
 
 Mesh-sharded serving: ``--sharded`` builds the host mesh
 (:func:`repro.launch.mesh.make_host_mesh`) and shards the engine over it
@@ -44,10 +48,12 @@ errors, stalls, malformed completions), and ``--remote-timeout`` /
 from __future__ import annotations
 
 import argparse
+import sys
 
 import jax
 
 from repro.configs import get_config, get_smoke_config, list_archs
+from repro.launch import compile_cache
 from repro.models import transformer as T
 from repro.serving import InferenceEngine
 from repro.training import load
@@ -64,7 +70,6 @@ def build_engine(arch: str, *, smoke: bool = True, checkpoint=None,
     ``paged`` switches the KV cache to the shared page pool with radix
     prefix reuse (``page_size`` tokens per page, ``num_pages`` total)."""
     cfg = get_smoke_config(arch) if smoke else get_config(arch)
-    cfg = cfg.replace(vocab_size=max(512, min(cfg.vocab_size, 512)))
     params = T.init_params(cfg, jax.random.PRNGKey(seed))
     if checkpoint:
         params, meta = load(checkpoint, params)
@@ -72,6 +77,19 @@ def build_engine(arch: str, *, smoke: bool = True, checkpoint=None,
     return InferenceEngine(cfg, params, max_seq_len=max_seq_len, mesh=mesh,
                            truncate_long=truncate_long, paged=paged,
                            page_size=page_size, num_pages=num_pages)
+
+
+def minions_tasks(n: int):
+    """The ``n`` synthetic MinionS requests that ``--minions n`` serves:
+    one round, one task per round, one page per chunk (two worker jobs
+    per request), 32-token worker answers."""
+    from repro.core import MinionSConfig, TaskSpec
+    from repro.core.tasks import make_task
+    cfg = MinionSConfig(max_rounds=1, num_tasks_per_round=1,
+                        pages_per_chunk=1, worker_max_tokens=32)
+    return [TaskSpec("minions", t.context, t.query, cfg)
+            for t in (make_task(700 + i, n_pages=2, kind="extract")
+                      for i in range(n))]
 
 
 def parse_replica_spec(spec: str) -> dict:
@@ -107,7 +125,7 @@ def build_fleet(args, mesh):
             paged = False
         eng = build_engine(
             spec.get("arch", args.arch), smoke=args.smoke,
-            checkpoint=args.checkpoint, mesh=mesh,
+            checkpoint=args.checkpoint, seed=args.seed, mesh=mesh,
             truncate_long=bool(args.minions), paged=paged,
             page_size=int(spec.get("page_size", args.page_size)),
             num_pages=int(spec.get("num_pages", args.num_pages)))
@@ -122,7 +140,13 @@ def build_fleet(args, mesh):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=list_archs())
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced smoke config (--no-smoke: published "
+                         "widths and vocabulary)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights (without "
+                         "--checkpoint)")
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--max-new-tokens", type=int, default=64)
     ap.add_argument("--temperature", type=float, default=0.2)
@@ -177,6 +201,7 @@ def main():
     ap.add_argument("--prompts", nargs="+",
                     default=["The total revenue for fiscal year 2015 was"])
     args = ap.parse_args()
+    compile_cache.configure()
 
     mesh = None
     if args.sharded:
@@ -194,16 +219,16 @@ def main():
               f"cost_weight={pool.cost_weight:g}")
     else:
         engine = build_engine(args.arch, smoke=args.smoke,
-                              checkpoint=args.checkpoint, mesh=mesh,
+                              checkpoint=args.checkpoint, seed=args.seed,
+                              mesh=mesh,
                               truncate_long=bool(args.minions),
                               paged=args.paged, page_size=args.page_size,
                               num_pages=args.num_pages)
     if args.minions:
-        from repro.core import MinionSConfig, ProtocolRunner, TaskSpec
+        from repro.core import ProtocolRunner
         from repro.core.clients import EngineClient, ResilientClient
         from repro.core.faults import FaultyClient
         from repro.core.simulated import ScriptedRemote
-        from repro.core.tasks import make_task
         remote = ScriptedRemote(seed=0)
         faulty = None
         if args.chaos:
@@ -224,12 +249,7 @@ def main():
         local = pool if pool is not None else \
             EngineClient(engine, max_batch=args.slots)
         runner = ProtocolRunner(local, remote)
-        cfg = MinionSConfig(max_rounds=1, num_tasks_per_round=1,
-                            pages_per_chunk=1, worker_max_tokens=32)
-        tasks = [make_task(700 + i, n_pages=2, kind="extract")
-                 for i in range(args.minions)]
-        results = runner.run([TaskSpec("minions", t.context, t.query, cfg)
-                              for t in tasks])
+        results = runner.run(minions_tasks(args.minions))
         for i, r in enumerate(results):
             err = f" error={r.error!r}" if r.error else ""
             print(f"task {i}: status={r.status} answer={r.answer!r} "
@@ -251,6 +271,10 @@ def main():
             _print_fleet(pool)
         else:
             print(f"usage: {engine.usage}")
+        # injected chaos may legitimately fail tasks; otherwise a failed
+        # task is a failed run
+        if not args.chaos and any(r.status != "ok" for r in results):
+            sys.exit(1)
         return
     if pool is not None:
         res = pool.run(args.prompts, temperature=args.temperature,
@@ -269,6 +293,8 @@ def main():
         print(f">>> {p!r}\n{o!r}\n")
     if pool is not None:
         _print_fleet(pool)
+        if any(r.error is not None for r in res):
+            sys.exit(1)
     else:
         print(f"usage: {engine.usage}")
 

@@ -622,6 +622,29 @@ def _decode_layer(lp: Params, lc: Cache, x, cfg: ModelConfig, kind: str,
     return x, lc
 
 
+def _decode_window(slot_mask, pos):
+    """(start, contiguous) of a dense decode step's written slot mask.
+
+    The Pallas decode kernel attends one [start, pos] window per row; it
+    serves the step only when that covers ``slot_mask`` exactly (every
+    row one contiguous valid region) — otherwise the step runs the
+    mask-honouring reference attention (see ``_decode_layer``)."""
+    start = jnp.argmax(slot_mask, axis=1).astype(jnp.int32)
+    slots = jnp.arange(slot_mask.shape[1])[None, :]
+    contiguous = jnp.all(
+        slot_mask == ((slots >= start[:, None]) & (slots < pos + 1)))
+    return start, contiguous
+
+
+def decode_falls_back(cfg: ModelConfig, cache: Cache, new_cache: Cache):
+    """int32 1 when the dense Pallas decode step ``cache`` -> ``new_cache``
+    took the reference branch of its ``lax.cond``, else 0."""
+    if not _pallas_attention_ok(cfg) or "slot_mask" not in cache:
+        return jnp.zeros((), jnp.int32)
+    _, contiguous = _decode_window(new_cache["slot_mask"], cache["pos"])
+    return (~contiguous).astype(jnp.int32)
+
+
 def decode_step(params: Params, cfg: ModelConfig, token: jnp.ndarray,
                 cache: Cache) -> Tuple[jnp.ndarray, Cache]:
     """One decode step.  token: (B, 1) int32 -> logits (B, 1, V).
@@ -641,11 +664,7 @@ def decode_step(params: Params, cfg: ModelConfig, token: jnp.ndarray,
     pallas_window = None
     if _pallas_attention_ok(cfg):
         # layer-invariant: analyse the slot mask once per decode step
-        start = jnp.argmax(slot_mask, axis=1).astype(jnp.int32)
-        slots = jnp.arange(slot_mask.shape[1])[None, :]
-        contiguous = jnp.all(
-            slot_mask == ((slots >= start[:, None]) & (slots < pos + 1)))
-        pallas_window = (start, contiguous)
+        pallas_window = _decode_window(slot_mask, pos)
 
     if cfg.scan_layers:
         p = cfg.scan_period()

@@ -132,6 +132,10 @@ class EngineUsage:
     admitted_jobs: int = 0
     finished_jobs: int = 0
     serve_epochs: int = 0
+    # serve decode steps whose dense slot mask had a hole, so the Pallas
+    # backend's decode ran the jnp reference attention instead of the
+    # kernel (always 0 on the reference backend and on paged engines)
+    decode_fallback_steps: int = 0
     # paged-KV counters (paged=True engines):
     #   pages_allocated     fresh pages handed out by the pool
     #   pages_reused        full pages attached to a row without a copy
@@ -329,7 +333,8 @@ def _serve_decode_loop(params, cfg: ModelConfig, tok, finished, out, n,
     unconditionally samples the next pending token — so at exit the
     surviving rows again hold pending tokens and resume seamlessly.  The
     price is one speculative ``decode_step`` per yield (O(admissions)
-    waste, not O(tokens)).
+    waste, not O(tokens)).  The last output counts the steps whose Pallas
+    decode fell back to reference attention (``T.decode_falls_back``).
 
     Stop detection mirrors the fused loop: the marker is emitted, rows
     whose window would start before their first emitted token never
@@ -341,11 +346,11 @@ def _serve_decode_loop(params, cfg: ModelConfig, tok, finished, out, n,
     cols = jnp.arange(buf_len)[None, :]
 
     def cond(st):
-        _tok, finished, _out, _n, _cache, _keys = st
+        _tok, finished, _out, _n, _cache, _keys, _fb = st
         return ~jnp.any(finished & live)
 
     def body(st):
-        tok, finished, out, n, cache, keys = st
+        tok, finished, out, n, cache, keys, fallbacks = st
         is_eos = tok == eos
         emit = live & ~finished & ~is_eos & (n < limit)
         idx = jnp.clip(n, 0, buf_len - 1)
@@ -362,13 +367,15 @@ def _serve_decode_loop(params, cfg: ModelConfig, tok, finished, out, n,
             finished = finished | (live & hit)
         finished = finished | (live & (n >= limit))
 
-        logits, cache = T.decode_step(params, cfg, tok[:, None], cache)
+        logits, new_cache = T.decode_step(params, cfg, tok[:, None], cache)
+        fallbacks = fallbacks + T.decode_falls_back(cfg, cache, new_cache)
         keys, sub = split_rows(keys)
         tok = sample_rows(logits[:, -1], sub, temperature)
-        return tok, finished, out, n, cache, keys
+        return tok, finished, out, n, new_cache, keys, fallbacks
 
     return jax.lax.while_loop(
-        cond, body, (tok, finished, out, n, cache, keys))
+        cond, body, (tok, finished, out, n, cache, keys,
+                     jnp.zeros((), jnp.int32)))
 
 
 class InferenceEngine:
@@ -1109,6 +1116,7 @@ class InferenceEngine:
                 self.usage.record("admit", j, pos, r)
             return sum(lens)
 
+        fallbacks = jnp.zeros((), jnp.int32)
         sanitize = _sanitize()
         if sanitize:
             xfer0 = self.usage.host_transfers
@@ -1158,9 +1166,10 @@ class InferenceEngine:
                     cache = None     # pool drained, nothing fits: new epoch
                     continue
 
-            tok, finished, out, n_emit, cache, keys = self._serve_loop(
+            tok, finished, out, n_emit, cache, keys, fb = self._serve_loop(
                 self.params, tok, finished, out, n_emit, cache, keys,
                 live, limit, temp, stop_ids, buf_len=buf_len)
+            fallbacks = fallbacks + fb
 
             # harvest — the only host<->device result transfers per yield
             fin_np = np.asarray(finished)
@@ -1182,16 +1191,19 @@ class InferenceEngine:
                 live = live.at[jnp.asarray(done_rows, jnp.int32)].set(False)
 
         self.usage.add(total_prefill, total_decode)
+        self.usage.decode_fallback_steps += int(fallbacks)
+        self.usage.host_transfers += 1
         if sanitize:
             # every 4-transfer harvest follows a wave that admitted or
-            # finished >= 1 job, so transfers stay O(admissions), never
-            # O(decoded tokens)
+            # finished >= 1 job, plus one read of the fallback count per
+            # call, so transfers stay O(admissions), never O(decoded
+            # tokens)
             used = self.usage.host_transfers - xfer0
             waves = (self.usage.admitted_jobs + self.usage.finished_jobs
                      - waves0)
-            assert used <= 4 * waves + 4, (
+            assert used <= 4 * waves + 5, (
                 f"host-transfer budget exceeded: {used} transfers for "
-                f"{waves} admit/finish events (budget 4*waves+4) — a "
+                f"{waves} admit/finish events (budget 4*waves+5) — a "
                 "per-token sync leaked into the serve loop")
         return [t if t is not None else "" for t in results]
 
@@ -1292,7 +1304,8 @@ class InferenceEngine:
                         self.usage.admitted_jobs += 1
                         self.usage.record("admit", p.jid, len(p.tokens), r)
 
-            tok, finished, out, n_emit, cache, keys = self._serve_loop(
+            # paged decode has no dense slot mask, hence no fallback count
+            tok, finished, out, n_emit, cache, keys, _ = self._serve_loop(
                 self.params, tok, finished, out, n_emit, cache, keys,
                 live, limit, temp, stop_ids, buf_len=buf_len)
 

@@ -91,7 +91,11 @@ def _oracle(arch):
 def _run_cell(arch, backend, path, pack, mesh_devices):
     eng = _engine(arch, backend, mesh_devices, pack)
     if path == "serve":
-        return eng.serve(PROMPTS, max_new_tokens=MAX_NEW, slots=8)
+        out = eng.serve(PROMPTS, max_new_tokens=MAX_NEW, slots=8)
+        # served rows keep one contiguous window: the Pallas decode never
+        # takes its reference fallback
+        assert eng.usage.decode_fallback_steps == 0
+        return out
     return eng.generate_batch(PROMPTS, max_new_tokens=MAX_NEW)
 
 

@@ -230,9 +230,12 @@ def test_pallas_decode_noncontiguous_mask_falls_back():
     cache["slot_mask"] = cache["slot_mask"].at[:, 10:15].set(False)
     tok = jnp.array([[65]], jnp.int32)
     lr, _ = T.decode_step(params, cfg_ref, tok, dict(cache))
-    lp, _ = T.decode_step(params, cfg_pal, tok, dict(cache))
+    lp, new_cache = T.decode_step(params, cfg_pal, tok, dict(cache))
     np.testing.assert_allclose(np.asarray(lr), np.asarray(lp), atol=1e-5,
                                rtol=1e-5)
+    # the step is counted as a fallback; the reference backend never is
+    assert int(T.decode_falls_back(cfg_pal, cache, new_cache)) == 1
+    assert int(T.decode_falls_back(cfg_ref, cache, new_cache)) == 0
 
 
 def test_pallas_backend_falls_back_on_sliding_window():
