@@ -1,0 +1,150 @@
+"""The work a window's traffic requires, counted from the traffic alone.
+
+Whatever the program does (padding, buckets, prefixes computed twice), the
+count is the least any exact implementation has to do, so a share of a peak
+built on it cannot read above what the chip did:
+
+- each distinct prompt token counts once, by a token trie over the prompts
+  of one closed-loop call (calls run one after another, so only one call's
+  prompts can share work);
+- each served token but a job's last is fed through one decode forward, and
+  a logit row is needed for each job's last prompt token and each of those
+  forwards;
+- attention counts each row's causal context;
+- decode attention reads a K/V token once per decode step for all the jobs
+  of a call that are still decoding and whose prompts share it.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Iterable, List, Sequence, Tuple
+
+
+@dataclasses.dataclass(frozen=True)
+class Job:
+    """One served worker job: its prompt ids, how many tokens it served,
+    and the closed-loop call it belonged to."""
+    prompt: Tuple[int, ...]
+    served: int
+    call: int = 0
+
+
+def _lcp(a: Sequence[int], b: Sequence[int]) -> int:
+    n = min(len(a), len(b))
+    i = 0
+    while i < n and a[i] == b[i]:
+        i += 1
+    return i
+
+
+def trie(prompts: Iterable[Sequence[int]]) -> Tuple[int, int]:
+    """(distinct tokens, sum of their causal contexts) of a token trie over
+    ``prompts``.  A trie node at position p (0-based) attends p + 1 keys.
+    Sorted prompts add exactly the nodes past their common prefix with
+    the previous one."""
+    nodes = ctx = 0
+    prev: Sequence[int] = ()
+    for p in sorted(tuple(p) for p in prompts):
+        lo, hi = _lcp(p, prev), len(p)
+        nodes += hi - lo
+        ctx += (hi * (hi + 1) - lo * (lo + 1)) // 2
+        prev = p
+    return nodes, ctx
+
+
+def _by_call(jobs: Sequence[Job]) -> List[List[Job]]:
+    calls = {}
+    for j in jobs:
+        calls.setdefault(j.call, []).append(j)
+    return [calls[c] for c in sorted(calls)]
+
+
+@dataclasses.dataclass(frozen=True)
+class Counts:
+    """Token counts behind every work function."""
+    prompt_tokens: int        # distinct prompt tokens (trie nodes)
+    prompt_ctx: int           # sum of their causal contexts
+    decode_forwards: int      # served tokens fed back, sum of (served - 1)
+    decode_ctx: int           # sum of their causal contexts
+    logit_rows: int           # jobs + decode forwards
+    decode_kv_reads: int      # K/V tokens read, once per step per share
+
+
+def counts(jobs: Sequence[Job]) -> Counts:
+    prompt_tokens = prompt_ctx = fwd = dctx = rows = kv = 0
+    for call in _by_call(jobs):
+        n, c = trie(j.prompt for j in call)
+        prompt_tokens += n
+        prompt_ctx += c
+        for j in call:
+            steps = max(j.served - 1, 0)
+            L = len(j.prompt)
+            fwd += steps
+            # forward k (1..steps) feeds served token k at position L+k-1
+            dctx += steps * L + steps * (steps + 1) // 2
+            rows += 1 + steps
+            # its own served tokens already in the cache at step k: k
+            kv += steps * (steps + 1) // 2
+        # shared prompt K/V, once per step over the jobs still decoding
+        # (steps 1..served-1); the live set changes only where a job ends
+        done = 0
+        for end in sorted({j.served - 1 for j in call if j.served > 1}):
+            live = [j.prompt for j in call if j.served - 1 >= end]
+            kv += (end - done) * trie(live)[0]
+            done = end
+    return Counts(prompt_tokens, prompt_ctx, fwd, dctx, rows, kv)
+
+
+def matmul_flops_per_token(dims) -> int:
+    """Linear-layer FLOPs of one token through every layer (no LM head)."""
+    d, hd = dims.d_model, dims.head_dim
+    per_layer = (d * dims.heads * hd + 2 * d * dims.kv_heads * hd
+                 + dims.heads * hd * d + 3 * d * dims.d_ff)
+    return 2 * per_layer * dims.layers
+
+
+def lm_head_flops(dims) -> int:
+    return 2 * dims.d_model * dims.vocab
+
+
+def attn_flops_per_key(dims) -> int:
+    """Q.K and P.V FLOPs of one query row against one key, all layers."""
+    return 4 * dims.heads * dims.head_dim * dims.layers
+
+
+def step_flops(dims, c: Counts) -> int:
+    """Everything the traffic requires of the model: linear layers for every
+    distinct prompt token and decode forward, attention over each row's
+    causal context, and the LM head where a logit is needed."""
+    return (matmul_flops_per_token(dims) * (c.prompt_tokens
+                                            + c.decode_forwards)
+            + attn_flops_per_key(dims) * (c.prompt_ctx + c.decode_ctx)
+            + lm_head_flops(dims) * c.logit_rows)
+
+
+def prefill_attn_work(dims, c: Counts) -> Tuple[int, int]:
+    """(FLOPs, bytes) of prefill attention: every distinct prompt row
+    against its causal context; Q and the output once per row, K and V
+    once per distinct token, every layer."""
+    b = dims.dtype_bytes * dims.head_dim * dims.layers
+    flops = attn_flops_per_key(dims) * c.prompt_ctx
+    byts = c.prompt_tokens * b * (2 * dims.heads + 2 * dims.kv_heads)
+    return flops, byts
+
+
+def decode_attn_work(dims, c: Counts) -> Tuple[int, int]:
+    """(FLOPs, bytes) of decode attention: each decode forward's query row
+    against its causal context; K/V reads as counted in ``counts``, plus
+    the query and output of each forward, every layer."""
+    b = dims.dtype_bytes * dims.head_dim * dims.layers
+    flops = attn_flops_per_key(dims) * c.decode_ctx
+    byts = (c.decode_kv_reads * 2 * dims.kv_heads * b
+            + c.decode_forwards * 2 * dims.heads * b)
+    return flops, byts
+
+
+def roofline_seconds(flops: int, byts: int, peak) -> Tuple[float, str]:
+    """The least time the chip needs for the work, and which bound sets it."""
+    t_f = flops / peak["bf16_flops_per_s"]
+    t_b = byts / peak["hbm_bytes_per_s"]
+    return (t_f, "flops") if t_f >= t_b else (t_b, "bytes")
