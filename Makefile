@@ -9,7 +9,7 @@ export PYTHONPATH := src
 
 PYTEST ?= python -m pytest
 
-.PHONY: smoke full bench chaos fleet lint
+.PHONY: smoke full chaos fleet lint
 
 # sub-minute loop: everything not marked slow (includes the equivalence
 # smoke subset — sharded serve, pallas packed, paged serve with radix
@@ -45,20 +45,3 @@ lint:
 	python -m repro.analysis.lint benchmarks examples --rules R1,R3
 	@if command -v ruff >/dev/null 2>&1; then ruff check src tests; \
 	else echo "ruff not installed; skipping style lint"; fi
-
-# engine benchmark scenarios (fused decode, packing, continuous batching,
-# paged-vs-dense prefix reuse, sharded-vs-single-device serve); rewrites
-# BENCH_engine.json and experiments/bench_results.csv
-bench:
-	python -m benchmarks.run --only engine
-
-# protocol-tier scenario: concurrent vs serial multi-task MinionS over one
-# shared engine pool (merges the "protocol" key into BENCH_engine.json)
-bench-protocol:
-	python -m benchmarks.run --only protocol
-
-# fleet scenario: 2-replica heterogeneous EnginePool (cheap dense +
-# costly paged, cost-aware routing) vs a single replica on the same
-# MinionS workload (merges the "fleet" key into BENCH_engine.json)
-bench-fleet:
-	python -m benchmarks.run --only fleet

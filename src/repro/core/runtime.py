@@ -365,7 +365,14 @@ class ProtocolRunner:
 
     # ------------------------------------------------------------------
     def run(self, specs: Sequence[TaskSpec]) -> List[ProtocolResult]:
-        """Run every task to completion; results in ``specs`` order."""
+        """Run every task to completion; results in ``specs`` order.
+
+        Each step is a ``protocol.step`` span of the profiler's trace, its
+        remote and local servicing ``protocol.remote`` and
+        ``protocol.local`` spans inside it (jax is imported here, not at
+        module import, so the protocol layer stays importable without
+        it)."""
+        from jax.profiler import TraceAnnotation
         tids = [s.task_id if s.task_id is not None else i
                 for i, s in enumerate(specs)]
         if len(set(tids)) != len(tids):
@@ -386,26 +393,33 @@ class ProtocolRunner:
                               if isinstance(t.pending, RemoteCall)]
             if not local_waiters and not remote_waiters:
                 break
-            replies: List[Tuple[_LiveTask, Any]] = []
-            if remote_waiters:
-                replies += self._service_remote(remote_waiters)
-            if local_waiters:
-                replies += self._service_local(local_waiters)
-            # meters were updated during servicing; only now resume the
-            # generators (so a task resumed early can't see a step's
-            # drain half-dispatched)
-            for t, value in replies:
-                t.pending = None
-                if isinstance(value, _Throw):
-                    t.faults += 1
-                    self.faults_delivered += 1
-                    t.advance(value.exc, throw=True)
-                else:
-                    if isinstance(value, RemoteFailure):
+            with TraceAnnotation("protocol.step",
+                                 remote=len(remote_waiters),
+                                 local=len(local_waiters)):
+                replies: List[Tuple[_LiveTask, Any]] = []
+                if remote_waiters:
+                    with TraceAnnotation("protocol.remote",
+                                         tasks=len(remote_waiters)):
+                        replies += self._service_remote(remote_waiters)
+                if local_waiters:
+                    with TraceAnnotation("protocol.local",
+                                         tasks=len(local_waiters)):
+                        replies += self._service_local(local_waiters)
+                # meters were updated during servicing; only now resume
+                # the generators (so a task resumed early can't see a
+                # step's drain half-dispatched)
+                for t, value in replies:
+                    t.pending = None
+                    if isinstance(value, _Throw):
                         t.faults += 1
                         self.faults_delivered += 1
-                        self.degradations += 1
-                    t.advance(value)
+                        t.advance(value.exc, throw=True)
+                    else:
+                        if isinstance(value, RemoteFailure):
+                            t.faults += 1
+                            self.faults_delivered += 1
+                            self.degradations += 1
+                        t.advance(value)
         return [t.result for t in tasks]
 
     def run_one(self, protocol, context: str, query: str,

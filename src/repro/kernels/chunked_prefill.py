@@ -132,6 +132,7 @@ def chunked_prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         compiler_params=flash.compiler_params("parallel", "parallel",
                                               "arbitrary"),
         interpret=interpret,
+        name="chunked_prefill_attention",
     )(q, k, v, segment_ids[:, :, None],
       segment_ids[:, None, :]).astype(q.dtype)
 
@@ -213,4 +214,5 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         compiler_params=flash.compiler_params("parallel", "parallel",
                                               "arbitrary"),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(page_table, q, k_pool, v_pool, positions[:, :, None]).astype(q.dtype)

@@ -94,6 +94,8 @@ def _decode_call(first, valid_len, q, k, v, *, block_k: int, n_blocks: int,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, hd), q.dtype),
         compiler_params=flash.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        # the trace names the kernel by its jitted wrapper's name
+        name="paged_gqa_decode_attention" if paged else "gqa_decode_attention",
     )(first, valid_len, qg, k, v)
     return out.reshape(b, h, hd)
 

@@ -143,22 +143,25 @@ def init_params(cfg: ModelConfig, key) -> Params:
 
 
 def _self_attention(lp: Params, cfg: ModelConfig, x, positions, segment_ids):
-    hd = cfg.resolved_head_dim
-    q, k, v = qkv_project(lp["attn"], x, cfg.num_heads, cfg.num_kv_heads, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    b, s = x.shape[:2]
-    if _pallas_attention_ok(cfg):
-        seg = (segment_ids if segment_ids is not None
-               else jnp.zeros((b, s), jnp.int32))
-        out = pallas_kernels.chunked_prefill(q, k, v, seg)
-    else:
-        kr = repeat_kv(k, cfg.q_per_kv)
-        vr = repeat_kv(v, cfg.q_per_kv)
-        out = attention(q, kr, vr, causal=True, window=cfg.sliding_window,
-                        segment_ids=segment_ids)
-    out = out.reshape(b, s, cfg.num_heads * hd) @ lp["attn"]["wo"]
-    return out, (k, v)
+    with jax.named_scope("attn"):
+        hd = cfg.resolved_head_dim
+        q, k, v = qkv_project(lp["attn"], x, cfg.num_heads,
+                              cfg.num_kv_heads, hd)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+        b, s = x.shape[:2]
+        if _pallas_attention_ok(cfg):
+            seg = (segment_ids if segment_ids is not None
+                   else jnp.zeros((b, s), jnp.int32))
+            out = pallas_kernels.chunked_prefill(q, k, v, seg)
+        else:
+            kr = repeat_kv(k, cfg.q_per_kv)
+            vr = repeat_kv(v, cfg.q_per_kv)
+            out = attention(q, kr, vr, causal=True,
+                            window=cfg.sliding_window,
+                            segment_ids=segment_ids)
+        out = out.reshape(b, s, cfg.num_heads * hd) @ lp["attn"]["wo"]
+        return out, (k, v)
 
 
 def _cross_attention(attn_p: Params, cfg: ModelConfig, x, memory):
@@ -173,21 +176,23 @@ def _cross_attention(attn_p: Params, cfg: ModelConfig, x, memory):
 
 
 def _ffn(lp: Params, cfg: ModelConfig, x, aux_sink=None):
-    h = rms_norm(x, lp["norm2"], cfg.norm_eps)
-    if cfg.is_moe:
-        if aux_sink is not None:
-            out, aux = moe_lib.moe_ffn(
-                lp["moe"], h, num_experts=cfg.num_experts,
-                top_k=cfg.num_experts_per_tok,
-                capacity_factor=cfg.expert_capacity_factor, return_aux=True)
-            aux_sink.append(aux)
-            return out
-        return moe_lib.moe_ffn(lp["moe"], h, num_experts=cfg.num_experts,
-                               top_k=cfg.num_experts_per_tok,
-                               capacity_factor=cfg.expert_capacity_factor)
-    if cfg.family == "audio":
-        return gelu_mlp(lp["mlp"], h)
-    return swiglu(lp["mlp"], h)
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["norm2"], cfg.norm_eps)
+        if cfg.is_moe:
+            if aux_sink is not None:
+                out, aux = moe_lib.moe_ffn(
+                    lp["moe"], h, num_experts=cfg.num_experts,
+                    top_k=cfg.num_experts_per_tok,
+                    capacity_factor=cfg.expert_capacity_factor,
+                    return_aux=True)
+                aux_sink.append(aux)
+                return out
+            return moe_lib.moe_ffn(lp["moe"], h, num_experts=cfg.num_experts,
+                                   top_k=cfg.num_experts_per_tok,
+                                   capacity_factor=cfg.expert_capacity_factor)
+        if cfg.family == "audio":
+            return gelu_mlp(lp["mlp"], h)
+        return swiglu(lp["mlp"], h)
 
 
 def run_encoder(params: Params, cfg: ModelConfig, enc_embeddings):
@@ -309,9 +314,10 @@ def _decoder_layer(lp: Params, x, cfg: ModelConfig, kind: str, positions,
 
 
 def _lm_head(params, x):
-    if "lm_head" in params:
-        return x @ params["lm_head"]
-    return x @ params["embed"].T
+    with jax.named_scope("lm_head"):
+        if "lm_head" in params:
+            return x @ params["lm_head"]
+        return x @ params["embed"].T
 
 
 lm_head = _lm_head  # public: engine reads logits at packed-job positions
@@ -502,8 +508,9 @@ def _prefill_layer(lp: Params, lc: Cache, x, cfg: ModelConfig, kind: str,
     h = rms_norm(x, lp["norm1"], cfg.norm_eps)
     if kind == "attn":
         out, (k, v) = _self_attention(lp, cfg, h, positions, segment_ids)
-        _write_kv(lc, "k", k, 0, cfg)
-        _write_kv(lc, "v", v, 0, cfg)
+        with jax.named_scope("kv_write"):
+            _write_kv(lc, "k", k, 0, cfg)
+            _write_kv(lc, "v", v, 0, cfg)
         x = x + out
         if cfg.is_encdec:
             hx = rms_norm(x, lp["xnorm"], cfg.norm_eps)
@@ -769,19 +776,24 @@ def _paged_prefill_layer(lp: Params, lc: Cache, x, cfg: ModelConfig,
                          positions, page_table, dst_page, dst_slot):
     b, s, _ = x.shape
     hd = cfg.resolved_head_dim
-    h = rms_norm(x, lp["norm1"], cfg.norm_eps)
-    q, k, v = qkv_project(lp["attn"], h, cfg.num_heads, cfg.num_kv_heads, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    kp = lc["k"].at[dst_page, dst_slot].set(k.astype(lc["k"].dtype))
-    vp = lc["v"].at[dst_page, dst_slot].set(v.astype(lc["v"].dtype))
-    if _pallas_attention_ok(cfg):
-        attn_out = pallas_kernels.paged_prefill(q, kp, vp, page_table,
-                                                positions)
-    else:
-        attn_out = kernels_ref.paged_prefill_ref(q, kp, vp, page_table,
-                                                 positions)
-    x = x + attn_out.reshape(b, s, cfg.num_heads * hd) @ lp["attn"]["wo"]
+    with jax.named_scope("attn"):
+        h = rms_norm(x, lp["norm1"], cfg.norm_eps)
+        q, k, v = qkv_project(lp["attn"], h, cfg.num_heads,
+                              cfg.num_kv_heads, hd)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("kv_write"):
+        kp = lc["k"].at[dst_page, dst_slot].set(k.astype(lc["k"].dtype))
+        vp = lc["v"].at[dst_page, dst_slot].set(v.astype(lc["v"].dtype))
+    with jax.named_scope("attn"):
+        if _pallas_attention_ok(cfg):
+            attn_out = pallas_kernels.paged_prefill(q, kp, vp, page_table,
+                                                    positions)
+        else:
+            attn_out = kernels_ref.paged_prefill_ref(q, kp, vp, page_table,
+                                                     positions)
+        x = x + (attn_out.reshape(b, s, cfg.num_heads * hd)
+                 @ lp["attn"]["wo"])
     if cfg.d_ff:
         x = x + _ffn(lp, cfg, x)
     return x, {"k": kp, "v": vp}
@@ -820,21 +832,27 @@ def _paged_decode_layer(lp: Params, lc: Cache, x, cfg: ModelConfig,
                         positions, page_table, row_len, dst_page, dst_slot):
     b = x.shape[0]
     hd = cfg.resolved_head_dim
-    h = rms_norm(x, lp["norm1"], cfg.norm_eps)
-    q, k, v = qkv_project(lp["attn"], h, cfg.num_heads, cfg.num_kv_heads, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    kp = lc["k"].at[dst_page, dst_slot].set(k[:, 0].astype(lc["k"].dtype))
-    vp = lc["v"].at[dst_page, dst_slot].set(v[:, 0].astype(lc["v"].dtype))
-    valid = row_len + 1
-    if _pallas_attention_ok(cfg):
-        attn_out = pallas_kernels.paged_gqa_decode(q, kp, vp, page_table,
-                                                   valid)
-    else:
-        attn_out = kernels_ref.paged_gqa_decode_ref(q[:, 0], kp, vp,
-                                                    page_table,
-                                                    valid)[:, None]
-    x = x + attn_out.reshape(b, 1, cfg.num_heads * hd) @ lp["attn"]["wo"]
+    with jax.named_scope("attn"):
+        h = rms_norm(x, lp["norm1"], cfg.norm_eps)
+        q, k, v = qkv_project(lp["attn"], h, cfg.num_heads,
+                              cfg.num_kv_heads, hd)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("kv_write"):
+        kp = lc["k"].at[dst_page, dst_slot].set(
+            k[:, 0].astype(lc["k"].dtype))
+        vp = lc["v"].at[dst_page, dst_slot].set(
+            v[:, 0].astype(lc["v"].dtype))
+    with jax.named_scope("attn"):
+        valid = row_len + 1
+        if _pallas_attention_ok(cfg):
+            attn_out = pallas_kernels.paged_gqa_decode(q, kp, vp,
+                                                       page_table, valid)
+        else:
+            attn_out = kernels_ref.paged_gqa_decode_ref(
+                q[:, 0], kp, vp, page_table, valid)[:, None]
+        x = x + (attn_out.reshape(b, 1, cfg.num_heads * hd)
+                 @ lp["attn"]["wo"])
     if cfg.d_ff:
         x = x + _ffn(lp, cfg, x)
     return x, {"k": kp, "v": vp}

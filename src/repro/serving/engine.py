@@ -136,6 +136,9 @@ class EngineUsage:
     # backend's decode ran the jnp reference attention instead of the
     # kernel (always 0 on the reference backend and on paged engines)
     decode_fallback_steps: int = 0
+    # iterations of the serve decode loop (one decode_step over every
+    # pool row each), counted on device and read once per serve call
+    decode_steps: int = 0
     # paged-KV counters (paged=True engines):
     #   pages_allocated     fresh pages handed out by the pool
     #   pages_reused        full pages attached to a row without a copy
@@ -272,7 +275,8 @@ def _fused_decode_loop(params, cfg: ModelConfig, first_logits, cache, key,
     temperature = jnp.asarray(temperature, jnp.float32)
 
     key, sk = jax.random.split(key)
-    tok0 = sample_traced(first_logits, sk, temperature, greedy=greedy)
+    with jax.named_scope("sample"):
+        tok0 = sample_traced(first_logits, sk, temperature, greedy=greedy)
     out0 = jnp.full((b, buf_len), pad, jnp.int32)
     state = (jnp.zeros((), jnp.int32), tok0, jnp.zeros((b,), bool), out0,
              jnp.zeros((), jnp.int32), cache, key)
@@ -303,8 +307,10 @@ def _fused_decode_loop(params, cfg: ModelConfig, first_logits, cache, key,
             tok, cache, key = operand
             logits, cache = T.decode_step(params, cfg, tok[:, None], cache)
             key, sk = jax.random.split(key)
-            return (sample_traced(logits[:, -1], sk, temperature,
-                                  greedy=greedy), cache, key)
+            with jax.named_scope("sample"):
+                tok = sample_traced(logits[:, -1], sk, temperature,
+                                    greedy=greedy)
+            return tok, cache, key
 
         tok, cache, key = jax.lax.cond(cont, advance, lambda op: op,
                                        (tok, cache, key))
@@ -315,8 +321,8 @@ def _fused_decode_loop(params, cfg: ModelConfig, first_logits, cache, key,
 
 
 def _serve_decode_loop(params, cfg: ModelConfig, tok, finished, out, n,
-                       cache, keys, live, limit, temperature, stop_ids, *,
-                       buf_len: int):
+                       cache, keys, live, limit, temperature, stop_ids,
+                       meter, *, buf_len: int):
     """Slot-pool decode: run until ANY live row finishes, then yield.
 
     Unlike :func:`_fused_decode_loop` (which drains a whole batch), this
@@ -333,8 +339,11 @@ def _serve_decode_loop(params, cfg: ModelConfig, tok, finished, out, n,
     unconditionally samples the next pending token — so at exit the
     surviving rows again hold pending tokens and resume seamlessly.  The
     price is one speculative ``decode_step`` per yield (O(admissions)
-    waste, not O(tokens)).  The last output counts the steps whose Pallas
-    decode fell back to reference attention (``T.decode_falls_back``).
+    waste, not O(tokens)).  ``meter`` (int32 (2,)) is a running total of
+    (loop iterations, steps whose Pallas decode fell back to reference
+    attention — ``T.decode_falls_back``), passed in and returned as the
+    last output, so a serve call sums every yield's counts on device and
+    reads them once.
 
     Stop detection mirrors the fused loop: the marker is emitted, rows
     whose window would start before their first emitted token never
@@ -346,11 +355,11 @@ def _serve_decode_loop(params, cfg: ModelConfig, tok, finished, out, n,
     cols = jnp.arange(buf_len)[None, :]
 
     def cond(st):
-        _tok, finished, _out, _n, _cache, _keys, _fb = st
+        _tok, finished, _out, _n, _cache, _keys, _meter = st
         return ~jnp.any(finished & live)
 
     def body(st):
-        tok, finished, out, n, cache, keys, fallbacks = st
+        tok, finished, out, n, cache, keys, meter = st
         is_eos = tok == eos
         emit = live & ~finished & ~is_eos & (n < limit)
         idx = jnp.clip(n, 0, buf_len - 1)
@@ -368,14 +377,16 @@ def _serve_decode_loop(params, cfg: ModelConfig, tok, finished, out, n,
         finished = finished | (live & (n >= limit))
 
         logits, new_cache = T.decode_step(params, cfg, tok[:, None], cache)
-        fallbacks = fallbacks + T.decode_falls_back(cfg, cache, new_cache)
-        keys, sub = split_rows(keys)
-        tok = sample_rows(logits[:, -1], sub, temperature)
-        return tok, finished, out, n, new_cache, keys, fallbacks
+        meter = meter + jnp.stack([
+            jnp.ones((), jnp.int32),
+            T.decode_falls_back(cfg, cache, new_cache)])
+        with jax.named_scope("sample"):
+            keys, sub = split_rows(keys)
+            tok = sample_rows(logits[:, -1], sub, temperature)
+        return tok, finished, out, n, new_cache, keys, meter
 
     return jax.lax.while_loop(
-        cond, body, (tok, finished, out, n, cache, keys,
-                     jnp.zeros((), jnp.int32)))
+        cond, body, (tok, finished, out, n, cache, keys, meter))
 
 
 class InferenceEngine:
@@ -463,28 +474,44 @@ class InferenceEngine:
         self._kv_pool = None
         self._pool_bytes = 0
 
-        self._prefill = jax.jit(
-            partial(T.prefill, cfg=cfg), static_argnames=("capacity",))
-        self._prefill_hidden = jax.jit(
-            partial(T.prefill, cfg=cfg, return_hidden=True),
-            static_argnames=("capacity",))
-        self._decode = jax.jit(lambda params, tok, cache: T.decode_step(
-            params, cfg, tok, cache))
-        self._decode_loop = jax.jit(
-            lambda params, first_logits, cache, key, stop_ids, limit,
-            temperature, *, buf_len, greedy: _fused_decode_loop(
-                params, cfg, first_logits, cache, key, stop_ids, limit,
-                temperature, buf_len=buf_len, greedy=greedy),
-            static_argnames=("buf_len", "greedy"))
-        self._serve_loop = jax.jit(
-            lambda params, tok, finished, out, n, cache, keys, live, limit,
-            temperature, stop_ids, *, buf_len: _serve_decode_loop(
-                params, cfg, tok, finished, out, n, cache, keys, live,
-                limit, temperature, stop_ids, buf_len=buf_len),
-            static_argnames=("buf_len",))
-        self._paged_prefill_fn = jax.jit(
-            lambda params, toks, pos, pta, dstp, dsts, layers:
-            T.paged_prefill(params, cfg, toks, pos, pta, dstp, dsts, layers))
+        # every jitted program is a named function: the name is the
+        # program's name in the compiled module, in the profiler's
+        # dispatch spans and on the device trace's module line
+        def prefill(params, batch, capacity):
+            return T.prefill(params, cfg, batch, capacity)
+
+        def prefill_hidden(params, batch, capacity):
+            return T.prefill(params, cfg, batch, capacity,
+                             return_hidden=True)
+
+        def decode_step(params, tok, cache):
+            return T.decode_step(params, cfg, tok, cache)
+
+        def fused_decode(params, first_logits, cache, key, stop_ids, limit,
+                         temperature, *, buf_len, greedy):
+            return _fused_decode_loop(params, cfg, first_logits, cache, key,
+                                      stop_ids, limit, temperature,
+                                      buf_len=buf_len, greedy=greedy)
+
+        def serve_decode(params, tok, finished, out, n, cache, keys, live,
+                         limit, temperature, stop_ids, meter, *, buf_len):
+            return _serve_decode_loop(params, cfg, tok, finished, out, n,
+                                      cache, keys, live, limit, temperature,
+                                      stop_ids, meter, buf_len=buf_len)
+
+        def paged_prefill(params, toks, pos, pta, dstp, dsts, layers):
+            return T.paged_prefill(params, cfg, toks, pos, pta, dstp, dsts,
+                                   layers)
+
+        self._prefill = jax.jit(prefill, static_argnames=("capacity",))
+        self._prefill_hidden = jax.jit(prefill_hidden,
+                                       static_argnames=("capacity",))
+        self._decode = jax.jit(decode_step)
+        self._decode_loop = jax.jit(fused_decode,
+                                    static_argnames=("buf_len", "greedy"))
+        self._serve_loop = jax.jit(serve_decode,
+                                   static_argnames=("buf_len",))
+        self._paged_prefill_fn = jax.jit(paged_prefill)
         self._cow_fn = jax.jit(_cow_layers)
 
     # ------------------------------------------------------------------
@@ -988,59 +1015,70 @@ class InferenceEngine:
         n = len(prompts)
         if n == 0:
             return []
-        budgets = (list(max_new_tokens)
-                   if isinstance(max_new_tokens, (list, tuple))
-                   else [int(max_new_tokens)] * n)
-        temps = (list(temperature) if isinstance(temperature, (list, tuple))
-                 else [float(temperature)] * n)
-        if key is None:
-            key = jax.random.PRNGKey(0)
-        if per_job_keys is not None:
-            per_job_keys = jnp.asarray(per_job_keys, jnp.uint32)
-            if per_job_keys.shape[0] != n:
-                # a short array would gather-clamp to the last lane and
-                # silently correlate the overflow jobs' samples
-                raise ValueError(f"per_job_keys has {per_job_keys.shape[0]} "
-                                 f"rows for {n} jobs")
-        if not self.can_serve:
-            # degrade to the scheduler's grouped convoy path — the single
-            # implementation of param-class isolation (a greedy job never
-            # inherits a stochastic neighbour's temperature or budget) and
-            # within-class length grouping.  The plain-lambda target keeps
-            # the scheduler off its engine path, so no recursion.
-            from .scheduler import JobScheduler
-            sched = JobScheduler(
-                lambda ps, **kw: self.generate_batch(ps, stop=stop, **kw),
-                max_batch=max(slots, 1))
-            for j in range(n):
-                sched.submit(prompts[j], temperature=temps[j],
-                             max_new_tokens=budgets[j])
-            return [r.text for r in sched.drain(key=key,
-                                                lanes=per_job_keys)]
+        with jax.profiler.TraceAnnotation("engine.serve", jobs=n,
+                                          slots=slots):
+            budgets = (list(max_new_tokens)
+                       if isinstance(max_new_tokens, (list, tuple))
+                       else [int(max_new_tokens)] * n)
+            temps = (list(temperature)
+                     if isinstance(temperature, (list, tuple))
+                     else [float(temperature)] * n)
+            if key is None:
+                key = jax.random.PRNGKey(0)
+            if per_job_keys is not None:
+                per_job_keys = jnp.asarray(per_job_keys, jnp.uint32)
+                if per_job_keys.shape[0] != n:
+                    # a short array would gather-clamp to the last lane
+                    # and silently correlate the overflow jobs' samples
+                    raise ValueError(f"per_job_keys has "
+                                     f"{per_job_keys.shape[0]} rows for {n} "
+                                     "jobs")
+            if not self.can_serve:
+                # degrade to the scheduler's grouped convoy path — the
+                # single implementation of param-class isolation (a greedy
+                # job never inherits a stochastic neighbour's temperature
+                # or budget) and within-class length grouping.  The
+                # plain-lambda target keeps the scheduler off its engine
+                # path, so no recursion.
+                from .scheduler import JobScheduler
+                sched = JobScheduler(
+                    lambda ps, **kw: self.generate_batch(ps, stop=stop,
+                                                         **kw),
+                    max_batch=max(slots, 1))
+                for j in range(n):
+                    sched.submit(prompts[j], temperature=temps[j],
+                                 max_new_tokens=budgets[j])
+                return [r.text for r in sched.drain(key=key,
+                                                    lanes=per_job_keys)]
 
+            slots = max(1, min(slots, n))
+            if self.mesh is not None:
+                # round the pool up to whole rows per data shard: a 4-slot
+                # pool on an 8-way data axis would fall into the
+                # sequence-sharded cache fallback (reordered reductions,
+                # no bit-identity guarantee); surplus rows stay non-live
+                from repro.parallel.sharding import data_axis_size
+                da = data_axis_size(self.mesh)
+                slots = -(-slots // da) * da
+            prompt_ids = self._truncate(
+                [self.tokenizer.encode(p) for p in prompts])
+            self._bucket_checked(prompt_ids)   # raise early on long jobs
+            buf_len = _bucket(max(budgets + [1]), minimum=8)
+            stop_ids = jnp.asarray(
+                self.tokenizer.encode(stop, bos=False) if stop else [],
+                jnp.int32)
+
+            serve_rows = (self._serve_paged if self.paged
+                          else self._serve_dense)
+            return serve_rows(prompt_ids, n, budgets, temps, key,
+                              per_job_keys, stop, stop_ids, slots, buf_len)
+
+    # ------------------------------------------------------------------
+    def _serve_dense(self, prompt_ids, n, budgets, temps, key,
+                     per_job_keys, stop, stop_ids, slots, buf_len):
+        """Continuous batching over dense per-row caches: rows share one
+        decode position per cache epoch (see :meth:`serve`)."""
         pad = ByteTokenizer.PAD
-        slots = max(1, min(slots, n))
-        if self.mesh is not None:
-            # round the pool up to whole rows per data shard: a 4-slot
-            # pool on an 8-way data axis would fall into the
-            # sequence-sharded cache fallback (reordered reductions, no
-            # bit-identity guarantee); surplus rows just stay non-live
-            from repro.parallel.sharding import data_axis_size
-            da = data_axis_size(self.mesh)
-            slots = -(-slots // da) * da
-        prompt_ids = self._truncate(
-            [self.tokenizer.encode(p) for p in prompts])
-        self._bucket_checked(prompt_ids)     # raise early on over-long jobs
-        buf_len = _bucket(max(budgets + [1]), minimum=8)
-        stop_ids = jnp.asarray(
-            self.tokenizer.encode(stop, bos=False) if stop else [],
-            jnp.int32)
-
-        if self.paged:
-            return self._serve_paged(prompt_ids, n, budgets, temps, key,
-                                     per_job_keys, stop, stop_ids, slots,
-                                     buf_len)
-
         results: List[Optional[str]] = [None] * n
         queue = list(range(n))
         row_job = [-1] * slots
@@ -1116,7 +1154,7 @@ class InferenceEngine:
                 self.usage.record("admit", j, pos, r)
             return sum(lens)
 
-        fallbacks = jnp.zeros((), jnp.int32)
+        meter = jnp.zeros((2,), jnp.int32)
         sanitize = _sanitize()
         if sanitize:
             xfer0 = self.usage.host_transfers
@@ -1166,10 +1204,9 @@ class InferenceEngine:
                     cache = None     # pool drained, nothing fits: new epoch
                     continue
 
-            tok, finished, out, n_emit, cache, keys, fb = self._serve_loop(
+            tok, finished, out, n_emit, cache, keys, meter = self._serve_loop(
                 self.params, tok, finished, out, n_emit, cache, keys,
-                live, limit, temp, stop_ids, buf_len=buf_len)
-            fallbacks = fallbacks + fb
+                live, limit, temp, stop_ids, meter, buf_len=buf_len)
 
             # harvest — the only host<->device result transfers per yield
             fin_np = np.asarray(finished)
@@ -1191,11 +1228,10 @@ class InferenceEngine:
                 live = live.at[jnp.asarray(done_rows, jnp.int32)].set(False)
 
         self.usage.add(total_prefill, total_decode)
-        self.usage.decode_fallback_steps += int(fallbacks)
-        self.usage.host_transfers += 1
+        self._read_meter(meter)
         if sanitize:
             # every 4-transfer harvest follows a wave that admitted or
-            # finished >= 1 job, plus one read of the fallback count per
+            # finished >= 1 job, plus one read of the loop's meter per
             # call, so transfers stay O(admissions), never O(decoded
             # tokens)
             used = self.usage.host_transfers - xfer0
@@ -1206,6 +1242,14 @@ class InferenceEngine:
                 f"{waves} admit/finish events (budget 4*waves+5) — a "
                 "per-token sync leaked into the serve loop")
         return [t if t is not None else "" for t in results]
+
+    def _read_meter(self, meter):
+        """Read a serve call's decode-loop meter (one transfer): the loop's
+        iterations and its Pallas decode fallback steps."""
+        steps, fallbacks = (int(v) for v in np.asarray(meter))
+        self.usage.decode_steps += steps
+        self.usage.decode_fallback_steps += fallbacks
+        self.usage.host_transfers += 1
 
     # ------------------------------------------------------------------
     def _serve_paged(self, prompt_ids, n, budgets, temps, key,
@@ -1249,13 +1293,18 @@ class InferenceEngine:
             xfer0 = self.usage.host_transfers
             waves0 = self.usage.admitted_jobs + self.usage.finished_jobs
 
+        meter = jnp.zeros((2,), jnp.int32)
         while queue or any(j >= 0 for j in row_job):
             free = [r for r in range(slots) if row_job[r] == -1]
             if free and queue:
                 cand = queue[:len(free)]
-                plans, _ = self._plan_paged_wave(
-                    [(j, tuple(prompt_ids[j]), budgets[j]) for j in cand],
-                    strict=False)
+                with jax.profiler.TraceAnnotation(
+                        "engine.plan", candidates=len(cand)) as span:
+                    plans, deferred = self._plan_paged_wave(
+                        [(j, tuple(prompt_ids[j]), budgets[j])
+                         for j in cand], strict=False)
+                    span.set_metadata(planned=len(plans),
+                                      deferred=len(deferred))
                 if not plans and not any(j >= 0 for j in row_job):
                     j = cand[0]
                     raise RuntimeError(
@@ -1265,37 +1314,41 @@ class InferenceEngine:
                         f"num_pages (={self.num_pages}) or lower "
                         f"max_new_tokens")
                 if plans:
-                    first_logits, layers = self._admit_plans(
-                        plans, cache["layers"])
-                    cache["layers"] = layers
                     rows = free[:len(plans)]
                     jids = [p.jid for p in plans]
-                    pt_rows = np.zeros((len(plans), p_max), np.int32)
-                    rl_rows = np.zeros((len(plans),), np.int32)
-                    for i, p in enumerate(plans):
-                        pt_rows[i, :len(p.pages)] = p.pages
-                        rl_rows[i] = len(p.tokens)
-                    rows_arr = jnp.asarray(rows, jnp.int32)
-                    cache["page_table"] = cache["page_table"].at[
-                        rows_arr].set(jnp.asarray(pt_rows))
-                    cache["row_len"] = cache["row_len"].at[rows_arr].set(
-                        jnp.asarray(rl_rows))
-                    base = (per_job_keys[jnp.asarray(jids, jnp.int32)]
-                            if per_job_keys is not None
-                            else job_keys(key, jids))
-                    jkeys, sub = split_rows(base)
-                    jtemp = jnp.asarray([temps[j] for j in jids],
-                                        jnp.float32)
-                    tok = tok.at[rows_arr].set(
-                        sample_rows(first_logits, sub, jtemp))
-                    finished = finished.at[rows_arr].set(False)
-                    live = live.at[rows_arr].set(True)
-                    out = out.at[rows_arr].set(pad)
-                    n_emit = n_emit.at[rows_arr].set(0)
-                    keys = keys.at[rows_arr].set(jkeys)
-                    limit = limit.at[rows_arr].set(
-                        jnp.asarray([budgets[j] for j in jids], jnp.int32))
-                    temp = temp.at[rows_arr].set(jtemp)
+                    with jax.profiler.TraceAnnotation(
+                            "engine.admit", rows=len(plans),
+                            levels=len({p.level for p in plans}),
+                            suffix_tokens=sum(len(p.tokens) - p.matched
+                                              for p in plans)):
+                        first_logits, cache["layers"] = self._admit_plans(
+                            plans, cache["layers"])
+                        pt_rows = np.zeros((len(plans), p_max), np.int32)
+                        rl_rows = np.zeros((len(plans),), np.int32)
+                        for i, p in enumerate(plans):
+                            pt_rows[i, :len(p.pages)] = p.pages
+                            rl_rows[i] = len(p.tokens)
+                        rows_arr = jnp.asarray(rows, jnp.int32)
+                        cache["page_table"] = cache["page_table"].at[
+                            rows_arr].set(jnp.asarray(pt_rows))
+                        cache["row_len"] = cache["row_len"].at[
+                            rows_arr].set(jnp.asarray(rl_rows))
+                        base = (per_job_keys[jnp.asarray(jids, jnp.int32)]
+                                if per_job_keys is not None
+                                else job_keys(key, jids))
+                        jkeys, sub = split_rows(base)
+                        jtemp = jnp.asarray([temps[j] for j in jids],
+                                            jnp.float32)
+                        tok = tok.at[rows_arr].set(
+                            sample_rows(first_logits, sub, jtemp))
+                        finished = finished.at[rows_arr].set(False)
+                        live = live.at[rows_arr].set(True)
+                        out = out.at[rows_arr].set(pad)
+                        n_emit = n_emit.at[rows_arr].set(0)
+                        keys = keys.at[rows_arr].set(jkeys)
+                        limit = limit.at[rows_arr].set(jnp.asarray(
+                            [budgets[j] for j in jids], jnp.int32))
+                        temp = temp.at[rows_arr].set(jtemp)
                     for r, p in zip(rows, plans):
                         row_job[r] = p.jid
                         row_pages[r] = p.pages
@@ -1304,43 +1357,48 @@ class InferenceEngine:
                         self.usage.admitted_jobs += 1
                         self.usage.record("admit", p.jid, len(p.tokens), r)
 
-            # paged decode has no dense slot mask, hence no fallback count
-            tok, finished, out, n_emit, cache, keys, _ = self._serve_loop(
-                self.params, tok, finished, out, n_emit, cache, keys,
-                live, limit, temp, stop_ids, buf_len=buf_len)
+            with jax.profiler.TraceAnnotation("engine.decode"):
+                (tok, finished, out, n_emit, cache, keys,
+                 meter) = self._serve_loop(
+                    self.params, tok, finished, out, n_emit, cache, keys,
+                    live, limit, temp, stop_ids, meter, buf_len=buf_len)
 
             # harvest — the only host<->device result transfers per yield
-            fin_np = np.asarray(finished)
-            n_np = np.asarray(n_emit)
-            out_np = np.asarray(out)
-            self.usage.host_transfers += 3
-            done_rows = [r for r in range(slots)
-                         if row_job[r] >= 0 and fin_np[r]]
-            for r in done_rows:
-                j = row_job[r]
-                text = self.tokenizer.decode(out_np[r, :int(n_np[r])])
-                results[j] = text.split(stop)[0] if stop else text
-                total_decode += int(n_np[r])
-                row_job[r] = -1
-                self._release_pages(row_pages.pop(r))
-                self.usage.finished_jobs += 1
-                self.usage.record("finish", j,
-                                  len(prompt_ids[j]) + int(n_np[r]), r)
-            if done_rows:
-                done_arr = jnp.asarray(done_rows, jnp.int32)
-                live = live.at[done_arr].set(False)
-                # quarantine dead rows: their pages may be reallocated
-                # while the loop keeps speculatively decoding them, so
-                # writes must drop into the null page and reads must not
-                # touch freed pages
-                cache["page_table"] = cache["page_table"].at[done_arr].set(0)
-                cache["row_len"] = cache["row_len"].at[done_arr].set(0)
+            with jax.profiler.TraceAnnotation("engine.harvest") as span:
+                fin_np = np.asarray(finished)
+                n_np = np.asarray(n_emit)
+                out_np = np.asarray(out)
+                self.usage.host_transfers += 3
+                done_rows = [r for r in range(slots)
+                             if row_job[r] >= 0 and fin_np[r]]
+                span.set_metadata(rows=len(done_rows))
+                for r in done_rows:
+                    j = row_job[r]
+                    text = self.tokenizer.decode(out_np[r, :int(n_np[r])])
+                    results[j] = text.split(stop)[0] if stop else text
+                    total_decode += int(n_np[r])
+                    row_job[r] = -1
+                    self._release_pages(row_pages.pop(r))
+                    self.usage.finished_jobs += 1
+                    self.usage.record("finish", j,
+                                      len(prompt_ids[j]) + int(n_np[r]), r)
+                if done_rows:
+                    done_arr = jnp.asarray(done_rows, jnp.int32)
+                    live = live.at[done_arr].set(False)
+                    # quarantine dead rows: their pages may be reallocated
+                    # while the loop keeps speculatively decoding them, so
+                    # writes must drop into the null page and reads must
+                    # not touch freed pages
+                    cache["page_table"] = cache["page_table"].at[
+                        done_arr].set(0)
+                    cache["row_len"] = cache["row_len"].at[done_arr].set(0)
 
         # commit the decode-era pool: indexed prefix pages were never
         # written after indexing (decode lands beyond each prompt's full
         # pages), so the radix stays valid for future calls
         self._kv_pool = cache["layers"]
         self.usage.add(total_prefill, total_decode)
+        self._read_meter(meter)
         if sanitize:
             self._pool.audit()   # all rows released: catch page leaks
             if self._radix is not None:

@@ -217,46 +217,48 @@ class JobScheduler:
         self._lane_ids = set()
         if not expanded:
             return []
-        if lanes is not None and len(lanes) != len(expanded):
-            raise ValueError(f"lanes has {len(lanes)} rows for "
-                             f"{len(expanded)} expanded replicas")
-        if key is None:
-            key = jax.random.PRNGKey(seed)
-        self.drains += 1
-        self.jobs_drained += len(expanded)
-        if lanes is None:
-            lanes = _replica_lanes(key, expanded)
-        if self.engine is not None:
-            # Paged engines admit from the serve queue in submission order,
-            # so cluster prefix-sharing prompts ADJACENTLY here: jobs with
-            # a common instruction prefix land in the same admission wave,
-            # where the engine's planner shares their prefix pages.  Texts
-            # are un-permuted below; lanes travel with their jobs, so the
-            # reorder cannot perturb any replica's sample stream.
-            order = list(range(len(expanded)))
-            if getattr(self.engine, "paged", False):
-                order.sort(key=lambda ei: (expanded[ei][2].prompt, ei))
-            perm = [expanded[ei] for ei in order]
-            self.last_perm = [(ji, si) for ji, si, _ in perm]
-            try:
-                texts = self.engine.serve(
-                    [p.prompt for _, _, p in perm],
-                    max_new_tokens=[p.max_new_tokens for _, _, p in perm],
-                    temperature=[p.temperature for _, _, p in perm],
-                    key=key, per_job_keys=lanes[jnp.asarray(order)],
-                    slots=self.max_batch)
-            except Exception as e:         # noqa: BLE001 — one SPMD program
-                # the pool is one program: a serve failure is every row's
-                # failure, reported per row instead of wedging the drain
-                results = [ScheduledResult(ji, si, "", e)
-                           for ji, si, _ in expanded]
+        with jax.profiler.TraceAnnotation("scheduler.drain",
+                                          jobs=len(expanded)):
+            if lanes is not None and len(lanes) != len(expanded):
+                raise ValueError(f"lanes has {len(lanes)} rows for "
+                                 f"{len(expanded)} expanded replicas")
+            if key is None:
+                key = jax.random.PRNGKey(seed)
+            self.drains += 1
+            self.jobs_drained += len(expanded)
+            if lanes is None:
+                lanes = _replica_lanes(key, expanded)
+            if self.engine is not None:
+                # Paged engines admit from the serve queue in submission order,
+                # so cluster prefix-sharing prompts ADJACENTLY here: jobs with
+                # a common instruction prefix land in the same admission wave,
+                # where the engine's planner shares their prefix pages.  Texts
+                # are un-permuted below; lanes travel with their jobs, so the
+                # reorder cannot perturb any replica's sample stream.
+                order = list(range(len(expanded)))
+                if getattr(self.engine, "paged", False):
+                    order.sort(key=lambda ei: (expanded[ei][2].prompt, ei))
+                perm = [expanded[ei] for ei in order]
+                self.last_perm = [(ji, si) for ji, si, _ in perm]
+                try:
+                    texts = self.engine.serve(
+                        [p.prompt for _, _, p in perm],
+                        max_new_tokens=[p.max_new_tokens for _, _, p in perm],
+                        temperature=[p.temperature for _, _, p in perm],
+                        key=key, per_job_keys=lanes[jnp.asarray(order)],
+                        slots=self.max_batch)
+                except Exception as e:  # noqa: BLE001 — one SPMD program
+                    # the pool is one program: a serve failure is every row's
+                    # failure, reported per row instead of wedging the drain
+                    results = [ScheduledResult(ji, si, "", e)
+                               for ji, si, _ in expanded]
+                else:
+                    results = [ScheduledResult(ji, si, t)
+                               for (ji, si, _), t in zip(perm, texts)]
             else:
-                results = [ScheduledResult(ji, si, t)
-                           for (ji, si, _), t in zip(perm, texts)]
-        else:
-            results = self._drain_grouped(expanded, lanes)
-        results.sort(key=lambda r: (r.job_index, r.sample_index))
-        return results
+                results = self._drain_grouped(expanded, lanes)
+            results.sort(key=lambda r: (r.job_index, r.sample_index))
+            return results
 
     def _drain_grouped(self, expanded, lanes) -> List[ScheduledResult]:
         """Legacy convoy batching for plain generate callables: jobs with

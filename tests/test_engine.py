@@ -530,6 +530,7 @@ def test_usage_accumulates_across_serve_calls_and_resets(engine):
     assert eng.usage.finished_jobs == 4
     assert eng.usage.prefill_tokens > first[2]
     assert eng.usage.host_transfers > first[3]
+    assert eng.usage.decode_steps > 0
     assert len(eng.usage.events) == 8            # 4 admits + 4 finishes
 
     eng.usage.reset()
@@ -538,6 +539,7 @@ def test_usage_accumulates_across_serve_calls_and_resets(engine):
     assert eng.usage.prefill_tokens == 0
     assert eng.usage.decode_tokens == 0
     assert eng.usage.host_transfers == 0
+    assert eng.usage.decode_steps == 0
     assert eng.usage.serve_epochs == 0
     assert eng.usage.calls == 0
     assert eng.usage.events == []

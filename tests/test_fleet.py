@@ -4,8 +4,7 @@ backpressure, the JobScheduler facade, and seeded replica-kill chaos.
 Everything here runs against cheap fake replicas (plain generate
 callables — no JAX compile), so the battery stays in the smoke loop; the
 token-identity cells against real engines live in
-tests/test_equivalence.py (`fleet` cells) and the heterogeneous
-MinionS-workload acceptance run in benchmarks/run.py (`--only fleet`).
+tests/test_equivalence.py (`fleet` cells).
 """
 import pytest
 

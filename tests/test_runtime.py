@@ -303,10 +303,11 @@ def test_usage_meter_free_and_record():
 
 
 def test_protocol_bench_fast_variant_cross_task_batching():
-    """Miniature of ``benchmarks/run.py --only protocol``: 3 MinionS
+    """``benchmarks/run.py:protocol_scenario`` in miniature: 3 MinionS
     tasks over one real engine pool — concurrent execution serves the
     same jobs in strictly fewer drains AND fewer engine serve calls
-    (EngineUsage), with identical answers."""
+    (EngineUsage), with identical answers; slot occupancy comes from the
+    decode loop's own step count (``EngineUsage.decode_steps``)."""
     sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
     try:
         from benchmarks.run import protocol_scenario
