@@ -30,6 +30,8 @@ DEFAULT_DIMS = {
     "hkv": 8,      # kv head count (every KV block holds all kv heads)
     "h": 64,       # q head count (prefill q/out blocks hold all heads)
     "block_k": 256,  # decode KV tile
+    "page_size": 64,  # page size where a kernel reads it from the pool
+    "ppb": 8,      # pages a paged decode grid step (8 x 64 = 512 keys)
 }
 
 

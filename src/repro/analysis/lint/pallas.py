@@ -23,6 +23,9 @@ and totals the kernel's per-invocation VMEM footprint::
   footprint = 2 x sum(in/out block bytes)   # double-buffered pipeline
             + sum(scratch bytes)            # resident accumulators
 
+An operand left in HBM (``BlockSpec(memory_space=...)`` with no block
+shape, which the kernel copies by hand into its scratch) has no block.
+
 checked against the budget in ``repro-lint.toml`` (default ~16 MiB per
 TensorCore).  Computed footprints are appended to the report notes, so
 ``make lint`` prints what each kernel actually costs.
@@ -147,6 +150,9 @@ class PallasKernelRule(Rule):
                 imap = kw.value if isinstance(kw.value, ast.Lambda) else imap
         if args:
             block = block or args[0]
+        if block is None and any(kw.arg == "memory_space"
+                                 for kw in expr.keywords):
+            block = ast.Tuple(elts=[ast.Constant(0)])   # no VMEM block
         if len(args) > 1 and isinstance(args[1], ast.Lambda):
             imap = imap or args[1]
         return (block, imap, env)

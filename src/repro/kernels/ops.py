@@ -81,8 +81,9 @@ def paged_gqa_decode(q, k_pool, v_pool, page_table, valid_len):
     (num_pages, page_size, Hkv, hd); page_table (B,P) int32 (0 = null
     page); valid_len scalar or (B,).
 
-    No padding needed: the KV block is one page and padded page-table
-    columns point at the null page, masked by ``valid_len``."""
+    No padding needed: a KV block is whole pages (a power of two of them
+    that divides the page-table width) and padded page-table columns
+    point at the null page, masked by ``valid_len``."""
     squeeze = False
     if q.ndim == 4:
         q = q[:, 0]

@@ -264,9 +264,9 @@ def test_r6_computes_real_kernel_footprints():
                         rules=[VmemBudgetRule()], root=REPO_ROOT)
     assert report.findings == []
     notes = [n for n in report.notes if "VMEM footprint" in n]
-    assert len(notes) == 3   # chunked/paged prefill + the shared decode call
+    assert len(notes) == 4   # chunked/paged prefill + dense/paged decode
     assert all("scratch" in n for n in notes)
-    assert not any("0 KiB scratch" in n for n in notes)
+    assert not any("+ 0 KiB scratch" in n for n in notes)
 
 
 def test_r6_shrunk_budget_fails_real_kernels():

@@ -18,6 +18,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 from repro.configs import get_smoke_config
 from repro.kernels import ref as kernels_ref
+from repro.kernels.gqa_decode import pages_per_block
 from repro.kernels.ops import paged_gqa_decode, paged_prefill
 from repro.models import transformer as T
 from repro.serving import EngineUsage, InferenceEngine, PagePool, RadixIndex
@@ -289,8 +290,52 @@ def _pool_fixture(seed=0, b=3, n_pages=10, ps=8, hkv=2, group=2, hd=16):
     return q, kp, vp, pt, valid
 
 
-def test_paged_decode_kernel_matches_ref():
-    q, kp, vp, pt, valid = _pool_fixture()
+def _block_fixture(valid, width, *, shared=0, hkv=2, group=2, hd=128,
+                   ps=64, seed=0):
+    """Rows of ``valid`` tokens on pages of ``ps`` slots, each row on
+    pages of its own (rows 0 and 1 share their first ``shared``) and
+    null pages past its last live page.  At page size 64 and hd 128 a
+    grid step of the paged decode kernel carries several pages."""
+    rng = np.random.default_rng(seed)
+    lives = [-(-v // ps) for v in valid]
+    n_pages = 1 + sum(lives)
+    ids = iter(rng.permutation(np.arange(1, n_pages)))
+    pt = np.zeros((len(valid), width), np.int32)
+    for row, live in enumerate(lives):
+        pt[row, :live] = [next(ids) for _ in range(live)]
+    pt[1, :shared] = pt[0, :shared]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    kp = jax.random.normal(ks[0], (n_pages, ps, hkv, hd), jnp.float32)
+    vp = jax.random.normal(ks[1], (n_pages, ps, hkv, hd), jnp.float32)
+    q = jax.random.normal(ks[2], (len(valid), hkv * group, hd), jnp.float32)
+    return q, kp, vp, jnp.asarray(pt), jnp.asarray(valid, jnp.int32)
+
+
+# each case with the pages a grid step carries (ppb) at its page-table width
+PAGED_DECODE_CASES = {
+    "one_page_blocks": lambda: _pool_fixture(),          # hd 16: one page
+    "narrow_head_dim_one_page_steps": lambda: _block_fixture(
+        [5 * 64 + 17, 130, 700], 16, hd=64),
+    "valid_mid_page": lambda: _block_fixture([5 * 64 + 17, 11 * 64 + 3,
+                                              40], 16),            # ppb 8
+    "valid_on_page_boundary": lambda: _block_fixture([3 * 64, 10 * 64, 64],
+                                                     16),
+    "valid_on_block_boundary": lambda: _block_fixture([512, 1024, 513], 16),
+    "tail_blocks_all_null": lambda: _block_fixture([100, 20, 700], 32),
+    "rows_share_prefix_pages": lambda: _block_fixture(
+        [9 * 64 + 5, 12 * 64 + 40, 300], 16, shared=9),
+    "width_shrinks_block_to_4": lambda: _block_fixture([700, 130, 768], 12),
+    "width_shrinks_block_to_2": lambda: _block_fixture([200, 384], 6),
+    "gqa_hkv4_group8": lambda: _block_fixture([1000, 300, 577], 16, hkv=4,
+                                              group=8, hd=128),
+    "multi_head_group1": lambda: _block_fixture([900, 64, 1021], 16, hkv=8,
+                                                group=1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_DECODE_CASES))
+def test_paged_decode_kernel_matches_ref(case):
+    q, kp, vp, pt, valid = PAGED_DECODE_CASES[case]()
     out = paged_gqa_decode(q, kp, vp, pt, valid)
     ref = kernels_ref.paged_gqa_decode_ref(q, kp, vp, pt, valid)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
@@ -305,6 +350,30 @@ def test_paged_prefill_kernel_matches_ref():
     out = paged_prefill(qs, kp, vp, pt, positions, block_q=8)
     ref = kernels_ref.paged_prefill_ref(qs, kp, vp, pt, positions)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("pool_shape, dtype, width, heads, expected", [
+    ((640, 64, 4, 128), jnp.bfloat16, 64, 32, 8),   # yi-6b serving shape
+    ((640, 64, 4, 128), jnp.bfloat16, 2, 32, 2),    # the width caps the block
+    ((640, 64, 4, 128), jnp.bfloat16, 12, 32, 4),   # largest 2^k dividing it
+    ((64, 512, 4, 128), jnp.bfloat16, 64, 32, 1),   # one page is 512 keys
+    ((64, 8, 4, 128), jnp.float32, 3, 32, 1),
+    ((92, 64, 32, 128), jnp.bfloat16, 64, 32, 4),   # multi-head: VMEM caps it
+    ((16, 64, 64, 256), jnp.float32, 64, 64, 1),
+])
+def test_pages_per_block_rule(pool_shape, dtype, width, heads, expected):
+    ppb = pages_per_block(pool_shape, dtype, width, heads)
+    assert ppb == expected
+    assert ppb <= width and width % ppb == 0
+
+
+def test_pages_per_block_never_exceeds_width():
+    for width in range(1, 70):
+        for page_size in (8, 16, 64, 128, 512, 1024):
+            ppb = pages_per_block((8, page_size, 4, 128), jnp.bfloat16,
+                                  width, 32)
+            assert 1 <= ppb <= width and width % ppb == 0
+            assert ppb == 1 or ppb * page_size <= 512
 
 
 # ---------------------------------------------------------------------------
