@@ -19,9 +19,10 @@ import sys
 import tempfile
 import time
 from pathlib import Path
-from typing import Callable, Dict, List, Optional
+from types import ModuleType
+from typing import Any, Callable, Dict, List, Optional
 
-from . import model as model_lib
+from . import model
 from . import reference, trace as trace_lib, traffic as traffic_lib, work
 from .spec import Spec
 
@@ -126,8 +127,10 @@ def recording_tokenizer():
 
 @dataclasses.dataclass
 class Context:
-    """What a metric reader sees."""
-    dims: model_lib.Dims
+    """What a metric reader sees: ``arch`` is the configuration's
+    architecture module, ``dims`` its sizes."""
+    arch: ModuleType
+    dims: Any
     peak: Optional[dict]
     jobs: List[work.Job]
     usage: Dict[str, int]
@@ -205,7 +208,9 @@ def run(workload: str, seed: int, seconds: float, traced: bool, *,
     events = ProgramEvents(jax)
 
     mix = dict(spec.traffic(cell["traffic"]))
-    dims = model_lib.load_dims(spec.config_path(cell["config"]))
+    config = spec.config(cell["config"])
+    arch = spec.arch_module(config["model_type"])
+    dims = arch.load_dims(config)
     backend = "pallas" if rehearse is None else rehearse.get("backend",
                                                               "reference")
     limits = spec.limits(workload)
@@ -222,9 +227,10 @@ def run(workload: str, seed: int, seconds: float, traced: bool, *,
     from repro.core.simulated import ScriptedRemote
     from repro.serving import InferenceEngine
 
-    params = model_lib.make_weights(dims, traffic_lib.derive(seed, "weights"))
+    params = model.make_weights(arch, dims,
+                                traffic_lib.derive(seed, "weights"))
     engine = InferenceEngine(
-        model_lib.program_config(dims, backend), params,
+        arch.program_config(dims, backend), params,
         tokenizer=recording_tokenizer(), max_seq_len=dims.max_seq_len,
         paged=True, page_size=dims.page_size, num_pages=dims.num_pages)
     jobs, state = attach_recorder(engine, jax.profiler.TraceAnnotation)
@@ -295,7 +301,7 @@ def run(workload: str, seed: int, seconds: float, traced: bool, *,
     del runner, client, engine
     gc.collect()
 
-    ctx = Context(dims=dims, peak=peak,
+    ctx = Context(arch=arch, dims=dims, peak=peak,
                   jobs=[work.Job(j.prompt, len(j.served), j.call)
                         for j in served],
                   usage=usage, window_programs=window_programs,
@@ -328,7 +334,7 @@ def run(workload: str, seed: int, seconds: float, traced: bool, *,
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
-    check_args = (params, dims, served, mix, limits, seed)
+    check_args = (arch, params, dims, served, mix, limits, seed)
     counts = dict(unanswered=unanswered, failed_tasks=failed)
     check = output_check(*check_args, **counts)
     if control:
@@ -369,7 +375,7 @@ def check_sample(served: List[ServedJob], mix: dict, seed: int
     return sample
 
 
-def output_check(params, dims, served, mix, limits, seed, *,
+def output_check(arch, params, dims, served, mix, limits, seed, *,
                  unanswered: int, failed_tasks: int,
                  quant: Optional[str] = None) -> dict:
     """The numbers ``correct`` compares, each with its limit:
@@ -379,7 +385,8 @@ def output_check(params, dims, served, mix, limits, seed, *,
       window that it never finished (exact, 0);
     - ``max_logit_gap``: over a sample of greedy jobs, the widest gap by
       which a served token's reference logit lies below the reference's
-      best, the reference run over the prompt with the served tokens.
+      best, the reference (``arch.logits``) run over the prompt with the
+      served tokens.
 
     With ``quant`` the control stands in the program's place: at each
     position of the same prompts and served tokens, the token that the
@@ -391,8 +398,8 @@ def output_check(params, dims, served, mix, limits, seed, *,
         gaps = []
         for j in sample:
             rows = reference.served_rows(len(j.prompt), len(j.served))
-            ref = reference.logits(params, dims, j.prompt + j.served, rows)
-            picked = j.served if quant is None else reference.logits(
+            ref = arch.logits(params, dims, j.prompt + j.served, rows)
+            picked = j.served if quant is None else arch.logits(
                 params, dims, j.prompt + j.served, rows,
                 quant=quant).argmax(-1)
             gaps.append(reference.served_gaps(ref, picked).max())

@@ -10,7 +10,7 @@ def read(ctx):
     window = programs.window_ops(ctx)
     if window is None:
         return None
-    steps = programs.decode_steps(*window, ctx.dims.layers)
+    steps = programs.decode_steps(*window, ctx.dims.attn_layers)
     if steps <= 0:
         return None
     return 100.0 * ctx.counts.decode_forwards / (ctx.dims.slots * steps)

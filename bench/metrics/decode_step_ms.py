@@ -2,7 +2,7 @@
 union of the serve loop's ``while`` op intervals in the traced window
 (``programs.decode_loop_seconds``), over the loop iterations that started
 in it (``programs.decode_steps``: the paged decode kernel runs once per
-layer in each)."""
+attention layer in each)."""
 
 from bench import programs
 
@@ -12,7 +12,7 @@ def read(ctx):
     if window is None:
         return None
     ops, lo, hi = window
-    steps = programs.decode_steps(ops, lo, hi, ctx.dims.layers)
+    steps = programs.decode_steps(ops, lo, hi, ctx.dims.attn_layers)
     seconds = programs.decode_loop_seconds(ops, lo, hi)
     if steps <= 0 or seconds <= 0:
         return None

@@ -1,6 +1,6 @@
 """prefill_attn_roofline (%): the least time the chip needs for the prefill
-attention the traffic requires (``work.prefill_attn_work``), over the device
-time of the paged prefill kernel's ops in the trace."""
+attention the traffic requires (the architecture's ``prefill_attn_work``),
+over the device time of the paged prefill kernel's ops in the trace."""
 
 from bench import work
 
@@ -15,8 +15,8 @@ def read(ctx):
     seconds = ctx.kernel_seconds(KERNEL)
     if seconds <= 0:
         return None
-    least, bound = work.roofline_seconds(
-        *work.prefill_attn_work(ctx.dims, ctx.counts), ctx.peak)
+    least, bound = work.roofline_seconds(*ctx.arch.prefill_attn_work(ctx),
+                                         ctx.peak)
     ctx.log(f"metric: prefill_attn_roofline bound by {bound}: least "
             f"{least!r} s over kernel {seconds!r} s")
     return 100.0 * least / seconds

@@ -6,9 +6,9 @@ loop of ``serve_decode``:
 
 - the loop is one ``while`` op per run, over its body's ops (which the op
   line holds too, under their own names);
-- each iteration runs the paged decode kernel once per layer (the Pallas
-  call is named ``paged_gqa_decode_attention``), so the kernel's ops over
-  the layer count are the loop's iterations.
+- each iteration runs the paged decode kernel once per attention layer
+  (the Pallas call is named ``paged_gqa_decode_attention``), so the
+  kernel's ops over ``dims.attn_layers`` are the loop's iterations.
 """
 from __future__ import annotations
 
@@ -34,11 +34,12 @@ def _family_ops(ops: Sequence, family: str, lo: float, hi: float) -> List:
             and trace_lib.op_family(o[0]) == family]
 
 
-def decode_steps(ops: Sequence, lo: float, hi: float, layers: int) -> int:
+def decode_steps(ops: Sequence, lo: float, hi: float,
+                 attn_layers: int) -> int:
     """Iterations of the serve loop that started inside [lo, hi]."""
     n = sum(1 for o in _family_ops(ops, DECODE_KERNEL, lo, hi)
             if o[1] >= lo)
-    return n // layers
+    return n // attn_layers
 
 
 def decode_loop_seconds(ops: Sequence, lo: float, hi: float) -> float:

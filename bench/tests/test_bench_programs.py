@@ -51,7 +51,8 @@ def _ctx(tr):
                          decode_kv_reads=0)
     return types.SimpleNamespace(
         trace=tr, trace_summary=trace.summary(tr),
-        dims=types.SimpleNamespace(layers=LAYERS, slots=4), counts=counts,
+        dims=types.SimpleNamespace(attn_layers=LAYERS, slots=4),
+        counts=counts,
         log=lambda msg: None)
 
 

@@ -10,28 +10,29 @@ import numpy as np
 import pytest
 
 from bench import harness, model, reference
-from bench.model import Dims
+from bench.spec import Spec
 
-DIMS = Dims(name="ref-test", layers=2, d_model=64, heads=4, kv_heads=2,
-            head_dim=16, d_ff=96, vocab=300, rope_theta=10000.0,
-            norm_eps=1e-5, dtype="float32", page_size=8, num_pages=64,
-            slots=3, max_seq_len=256)
+LLAMA = Spec().arch_module("llama")
+DIMS = LLAMA.Dims(name="ref-test", layers=2, d_model=64, heads=4,
+                  kv_heads=2, head_dim=16, d_ff=96, vocab=300,
+                  rope_theta=10000.0, norm_eps=1e-5, dtype="float32",
+                  page_size=8, num_pages=64, slots=3, max_seq_len=256)
 
 
 @pytest.fixture(scope="module")
 def params():
-    return model.make_weights(DIMS, 11)
+    return model.make_weights(LLAMA, DIMS, 11)
 
 
 def test_reference_matches_program_forward(params):
     """Full-sequence logits at every position: the program's dense forward
     (reference attention) and the plain reference."""
     from repro.models import transformer as T
-    cfg = model.program_config(DIMS, "reference")
+    cfg = LLAMA.program_config(DIMS, "reference")
     toks = np.random.default_rng(0).integers(0, 256, size=37)
     prog = np.asarray(T.forward(params, cfg, {"tokens": jnp.asarray(toks)[None]})[0])
     with jax.default_matmul_precision("highest"):
-        ref = reference.logits(params, DIMS, toks, list(range(37)))
+        ref = LLAMA.logits(params, DIMS, toks, list(range(37)))
     np.testing.assert_allclose(prog, ref, rtol=2e-4, atol=2e-4)
 
 
@@ -41,7 +42,7 @@ def test_reference_matches_served_paged_tokens(params, backend):
     decode through the page pool), the second call reusing the first's
     pages: every served token is the reference's best, to rounding."""
     from repro.serving import InferenceEngine
-    eng = InferenceEngine(model.program_config(DIMS, backend), params,
+    eng = InferenceEngine(LLAMA.program_config(DIMS, backend), params,
                           tokenizer=harness.recording_tokenizer(),
                           max_seq_len=DIMS.max_seq_len, paged=True,
                           page_size=DIMS.page_size,
@@ -58,7 +59,7 @@ def test_reference_matches_served_paged_tokens(params, backend):
     assert len(jobs) == 4 and all(len(j.served) == 6 for j in jobs)
     for j in jobs:
         rows = reference.served_rows(len(j.prompt), len(j.served))
-        ref = reference.logits(params, DIMS, j.prompt + j.served, rows)
+        ref = LLAMA.logits(params, DIMS, j.prompt + j.served, rows)
         assert reference.served_gaps(ref, j.served).max() < 1e-4
 
 
@@ -75,8 +76,8 @@ def test_fp8_control_reads_wider_gaps(params):
     dims = dataclasses.replace(DIMS)
     toks = np.random.default_rng(1).integers(0, 256, size=48)
     rows = list(range(8, 47))
-    ref = reference.logits(params, dims, toks, rows)
-    ctl = reference.logits(params, dims, toks, rows, quant="fp8")
+    ref = LLAMA.logits(params, dims, toks, rows)
+    ctl = LLAMA.logits(params, dims, toks, rows, quant="fp8")
     gaps = reference.served_gaps(ref, ctl.argmax(-1))
     assert gaps.max() > 1e-2
     assert np.abs(ctl - ref).max() > 10 * np.abs(ref).max() * 1e-4

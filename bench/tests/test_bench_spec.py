@@ -7,7 +7,6 @@ import shutil
 
 import pytest
 
-from bench.model import load_dims
 from bench.spec import ROOT, Spec
 
 SPEC = Spec()
@@ -19,9 +18,9 @@ def test_every_config_resolves():
         path = SPEC.config_path(c["name"])
         assert path.is_file()
         assert path.resolve().is_relative_to(ROOT / "bench")
-        dims = load_dims(path)
+        data = SPEC.config(c["name"])
+        dims = SPEC.arch_module(data["model_type"]).load_dims(data)
         assert dims.name == c["name"]
-        data = json.loads(path.read_text())
         assert set(c["reduced"]) == set(data["reduced"])
 
 
@@ -64,23 +63,32 @@ def test_contract_shape():
 
 
 def test_new_files_are_found_by_name(tmp_path):
-    """A later cell brings its own config, mix, metric and limits: new files
-    and entries only."""
+    """A later cell brings its own config, architecture, mix, metric and
+    limits: new files and entries only."""
     shutil.copytree(ROOT / "bench", tmp_path / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     data = json.loads((ROOT / "BENCHMARK.json").read_text())
     cfg = json.loads((ROOT / "bench/configs/yi-6b.json").read_text())
     cfg["name"] = "yi-6b-short"
     (tmp_path / "bench/configs/yi-6b-short.json").write_text(json.dumps(cfg))
+    (tmp_path / "bench/configs/toy.json").write_text(json.dumps(
+        dict(cfg, name="toy", model_type="toy")))
+    (tmp_path / "bench/arch/toy.py").write_text(
+        "import dataclasses\n\n\n"
+        "@dataclasses.dataclass(frozen=True)\n"
+        "class Dims:\n    name: str\n    attn_layers: int\n\n\n"
+        "def load_dims(config):\n"
+        "    return Dims(config['name'], 3)\n")
     (tmp_path / "bench/traffic/short.json").write_text(json.dumps(
         dict(SPEC.traffic("fanout"), document_pages=4)))
     (tmp_path / "bench/metrics/queue_wait_s.py").write_text(
         "def read(ctx):\n    return 1.5\n")
     (tmp_path / "bench/limits/yi-6b-short.short.json").write_text(
         json.dumps({"max_logit_gap": {"limit": 0.5}}))
-    data["configs"].append({"name": "yi-6b-short", "source": "x",
-                            "file": "bench/configs/yi-6b-short.json",
-                            "reduced": [], "why": "x"})
+    for name in ("yi-6b-short", "toy"):
+        data["configs"].append({"name": name, "source": "x",
+                                "file": f"bench/configs/{name}.json",
+                                "reduced": [], "why": "x"})
     data["workloads"].append({"name": "yi-6b-short.short",
                               "config": "yi-6b-short", "traffic": "short",
                               "chips": 1, "why": "x"})
@@ -91,7 +99,11 @@ def test_new_files_are_found_by_name(tmp_path):
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(data))
 
     spec = Spec(tmp_path)
-    assert load_dims(spec.config_path("yi-6b-short")).name == "yi-6b-short"
+    short = spec.config("yi-6b-short")
+    assert spec.arch_module(short["model_type"]).load_dims(short).name == \
+        "yi-6b-short"
+    toy = spec.config("toy")
+    assert spec.arch_module(toy["model_type"]).load_dims(toy).attn_layers == 3
     assert spec.traffic("short")["document_pages"] == 4
     assert spec.limits("yi-6b-short.short")["max_logit_gap"]["limit"] == 0.5
     assert spec.metric_module("queue_wait_s").read(None) == 1.5
@@ -109,6 +121,8 @@ def test_unknown_names_fail_loudly():
         SPEC.config_path("no-such-config")
     with pytest.raises(FileNotFoundError):
         SPEC.metric_module("no_such_metric")
+    with pytest.raises(FileNotFoundError, match="no_such_arch.py"):
+        SPEC.arch_module("no_such_arch")
 
 
 def test_peak_table_is_keyed_by_device_kind():

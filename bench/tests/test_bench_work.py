@@ -1,15 +1,22 @@
 """The work functions against counts made by hand at small shapes."""
 import dataclasses
+import types
 
 import pytest
 
 from bench import work
-from bench.model import Dims
+from bench.spec import Spec
 
-DIMS = Dims(name="t", layers=2, d_model=8, heads=4, kv_heads=2, head_dim=2,
-            d_ff=16, vocab=10, rope_theta=1e4, norm_eps=1e-5,
-            dtype="bfloat16", page_size=4, num_pages=8, slots=2,
-            max_seq_len=64)
+LLAMA = Spec().arch_module("llama")
+DIMS = LLAMA.Dims(name="t", layers=2, d_model=8, heads=4, kv_heads=2,
+                  head_dim=2, d_ff=16, vocab=10, rope_theta=1e4,
+                  norm_eps=1e-5, dtype="bfloat16", page_size=4, num_pages=8,
+                  slots=2, max_seq_len=64)
+
+
+def _ctx(counts):
+    """What the work functions read of a metric ``Context``."""
+    return types.SimpleNamespace(dims=DIMS, counts=counts)
 
 
 def brute_trie(prompts):
@@ -57,20 +64,20 @@ def test_calls_do_not_share_work():
 
 def test_flops_by_hand():
     # per layer: q 8*8, k 8*4, v 8*4, o 8*8, gate/up/down 3*8*16 = 576
-    assert work.matmul_flops_per_token(DIMS) == 2 * 576 * 2
-    assert work.lm_head_flops(DIMS) == 2 * 8 * 10
-    assert work.attn_flops_per_key(DIMS) == 4 * 4 * 2 * 2
+    assert LLAMA.matmul_flops_per_token(DIMS) == 2 * 576 * 2
+    assert LLAMA.lm_head_flops(DIMS) == 2 * 8 * 10
+    assert LLAMA.attn_flops_per_key(DIMS) == 4 * 4 * 2 * 2
     c = work.Counts(prompt_tokens=6, prompt_ctx=19, decode_forwards=3,
                     decode_ctx=17, logit_rows=5, decode_kv_reads=14)
-    assert work.step_flops(DIMS, c) == (2304 * 9 + 64 * (19 + 17) + 160 * 5)
+    assert LLAMA.step_flops(_ctx(c)) == (2304 * 9 + 64 * (19 + 17) + 160 * 5)
 
 
 def test_attention_work_by_hand():
     c = work.Counts(prompt_tokens=6, prompt_ctx=19, decode_forwards=3,
                     decode_ctx=17, logit_rows=5, decode_kv_reads=14)
     # bytes per element 2, head_dim 2, 2 layers -> 8 per head-row
-    assert work.prefill_attn_work(DIMS, c) == (64 * 19, 6 * 8 * (8 + 4))
-    assert work.decode_attn_work(DIMS, c) == (64 * 17,
+    assert LLAMA.prefill_attn_work(_ctx(c)) == (64 * 19, 6 * 8 * (8 + 4))
+    assert LLAMA.decode_attn_work(_ctx(c)) == (64 * 17,
                                               14 * 2 * 2 * 8 + 3 * 2 * 4 * 8)
 
 

@@ -13,6 +13,10 @@ built on it cannot read above what the chip did:
 - attention counts each row's causal context;
 - decode attention reads a K/V token once per decode step for all the jobs
   of a call that are still decoding and whose prompts share it.
+
+What a token costs in FLOPs and bytes is the architecture's: its module
+(``bench/arch/<model_type>.py``) turns these counts into ``step_flops``,
+``prefill_attn_work`` and ``decode_attn_work``.
 """
 from __future__ import annotations
 
@@ -93,54 +97,6 @@ def counts(jobs: Sequence[Job]) -> Counts:
             kv += (end - done) * trie(live)[0]
             done = end
     return Counts(prompt_tokens, prompt_ctx, fwd, dctx, rows, kv)
-
-
-def matmul_flops_per_token(dims) -> int:
-    """Linear-layer FLOPs of one token through every layer (no LM head)."""
-    d, hd = dims.d_model, dims.head_dim
-    per_layer = (d * dims.heads * hd + 2 * d * dims.kv_heads * hd
-                 + dims.heads * hd * d + 3 * d * dims.d_ff)
-    return 2 * per_layer * dims.layers
-
-
-def lm_head_flops(dims) -> int:
-    return 2 * dims.d_model * dims.vocab
-
-
-def attn_flops_per_key(dims) -> int:
-    """Q.K and P.V FLOPs of one query row against one key, all layers."""
-    return 4 * dims.heads * dims.head_dim * dims.layers
-
-
-def step_flops(dims, c: Counts) -> int:
-    """Everything the traffic requires of the model: linear layers for every
-    distinct prompt token and decode forward, attention over each row's
-    causal context, and the LM head where a logit is needed."""
-    return (matmul_flops_per_token(dims) * (c.prompt_tokens
-                                            + c.decode_forwards)
-            + attn_flops_per_key(dims) * (c.prompt_ctx + c.decode_ctx)
-            + lm_head_flops(dims) * c.logit_rows)
-
-
-def prefill_attn_work(dims, c: Counts) -> Tuple[int, int]:
-    """(FLOPs, bytes) of prefill attention: every distinct prompt row
-    against its causal context; Q and the output once per row, K and V
-    once per distinct token, every layer."""
-    b = dims.dtype_bytes * dims.head_dim * dims.layers
-    flops = attn_flops_per_key(dims) * c.prompt_ctx
-    byts = c.prompt_tokens * b * (2 * dims.heads + 2 * dims.kv_heads)
-    return flops, byts
-
-
-def decode_attn_work(dims, c: Counts) -> Tuple[int, int]:
-    """(FLOPs, bytes) of decode attention: each decode forward's query row
-    against its causal context; K/V reads as counted in ``counts``, plus
-    the query and output of each forward, every layer."""
-    b = dims.dtype_bytes * dims.head_dim * dims.layers
-    flops = attn_flops_per_key(dims) * c.decode_ctx
-    byts = (c.decode_kv_reads * 2 * dims.kv_heads * b
-            + c.decode_forwards * 2 * dims.heads * b)
-    return flops, byts
 
 
 def roofline_seconds(flops: int, byts: int, peak) -> Tuple[float, str]:
