@@ -19,6 +19,20 @@ across kv iterations.  A grid step owns the (block, H|Hkv, hd) tiles of
 every head (the arrays' own minor dims, as Mosaic requires) and loops
 over the heads inside.  Per-token ids travel as (B, S, 1) column and
 (B, 1, S) row views so their blocks are legal for any batch size.
+
+Paged prefill — grid: (batch, q blocks, page blocks).  A grid step folds
+``pages_per_block`` pages of one row (~512 keys) into the softmax state,
+DMA'd by hand from the pool in HBM into a double-buffered VMEM block
+while the previous block computes, as the paged decode kernel does; only
+pages at or before the q tile's last position are copied.  Each kv head
+then meets the block in one QKᵀ and one PV whose q side is all G of its
+q heads, stacked head-major into a (G * block_q, hd) operand that is laid
+out once per q tile, not once per step.  The block's rows interleave the
+kv heads of each slot; one kv head's rows are read out once per block
+(a strided load: of 32-bit words for a bfloat16 pool, where two rows
+share a word).  Operands stay in the stored dtype with f32 accumulation:
+the probabilities meet V rounded to V's dtype.  Where hd is narrower than
+128 lanes a grid step streams one page through the BlockSpec pipeline.
 """
 from __future__ import annotations
 
@@ -31,6 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import flash
+from .gqa_decode import pages_per_block
 
 LANES = 128
 
@@ -179,13 +194,21 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     q: (B, S, H, hd); pools (num_pages, page_size, Hkv, hd) at native kv
     head count; page_table (B, P) int32 (0 = null page); positions (B, S)
     int32 (left-pad queries with position 0 — they attend only slot 0 and
-    the caller drops their output).  The KV block is one page.  S must be
-    a multiple of block_q (ops.py pads).  Returns (B, S, H, hd).
+    the caller drops their output).  A grid step streams
+    ``pages_per_block`` pages; pages after a q tile's last position are
+    neither copied nor computed.  Where hd is not a multiple of 128 lanes,
+    or the pool's rows cannot be read one kv head at a time (a 16-bit
+    pool other than bfloat16, or an odd kv head count above 1), a grid
+    step streams one page through the BlockSpec pipeline.  S must be a
+    multiple of block_q (ops.py pads).  Returns (B, S, H, hd).
     """
     b, s, h, hd = q.shape
     _, ps, hkv, _ = k_pool.shape
     assert h % hkv == 0, (h, hkv)
     assert s % block_q == 0, (s, block_q)
+    if _reads_kv_heads(k_pool):
+        return _paged_block_call(q, k_pool, v_pool, page_table, positions,
+                                 block_q=block_q, interpret=interpret)
     p_max = page_table.shape[1]
     kernel = functools.partial(_paged_kernel, block_q=block_q, page_size=ps,
                                sm_scale=1.0 / math.sqrt(hd),
@@ -216,3 +239,208 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         interpret=interpret,
         name="paged_prefill_attention",
     )(page_table, q, k_pool, v_pool, positions[:, :, None]).astype(q.dtype)
+
+
+def _reads_kv_heads(pool) -> bool:
+    """Whether the multi-page call can take the pool: Mosaic copies a page
+    by hand only in whole 128-lane rows ("Slice shape along dimension 2
+    must be aligned to tiling (128)"), and reads one kv head's rows of
+    the flat block with a 32-bit strided load, which a bfloat16 pool
+    meets as word pairs of rows (one slot's kv heads 2i and 2i+1)."""
+    _, _, hkv, hd = pool.shape
+    return hd % LANES == 0 and (
+        pool.dtype == jnp.float32 or hkv == 1
+        or (pool.dtype == jnp.bfloat16 and hkv % 2 == 0))
+
+
+def _kv_head_rows(buf, slot, kh: int, hkv: int):
+    """Kv head ``kh``'s rows of the flat (keys * Hkv, hd) block in
+    ``buf[slot]``, as (keys, hd) in the pool's dtype.  A bfloat16 pool
+    packs rows 2i and 2i+1 into one 32-bit word, row 2i in the low half:
+    read the words of kh's pair with a strided load and keep kh's half,
+    which is kh's value as the top bits of an f32 (exact)."""
+    keys = buf.shape[1] // hkv
+    if hkv == 1:
+        return buf[slot]
+    if buf.dtype == jnp.float32:
+        return buf[slot, pl.ds(kh, keys, stride=hkv), :]
+    words = buf.bitcast(jnp.uint32)[slot, pl.ds(kh // 2, keys,
+                                                 stride=hkv // 2), :]
+    top = words << 16 if kh % 2 == 0 else words & jnp.uint32(0xFFFF0000)
+    return pltpu.bitcast(top, jnp.float32).astype(buf.dtype)
+
+
+def _paged_block_kernel(pt_ref, first_ref, last_ref, q_ref, pos_ref, k_hbm,
+                        v_hbm, out_ref, k_buf, v_buf, sems, slot_ref,
+                        q_buf, qpos_ref, acc_ref, m_ref, l_ref, *,
+                        ppb: int, page_size: int, sm_scale: float):
+    """``first_ref``/``last_ref`` (B, num_q_blocks): the least and the
+    largest position of each q tile."""
+    bb = pl.program_id(0)
+    qi = pl.program_id(1)
+    kj = pl.program_id(2)
+    n_rows = pl.num_programs(0)
+    n_tiles = pl.num_programs(1)
+    n_blocks = pl.num_programs(2)
+    block_q, h, _ = q_ref.shape
+    hkv = q_buf.shape[0]
+    group = h // hkv
+    block = ppb * page_size                   # keys of a grid step
+    rows = page_size * hkv                    # flat-view rows of one page
+
+    def copy_block(row, tile, j, slot, wait: bool):
+        """Start (or wait for) the copies of block ``j`` of ``row`` for q
+        tile ``tile`` into ``slot``: one K and one V copy per page that
+        starts at or before the tile's last position."""
+        for i in range(ppb):
+            col = j * ppb + i
+
+            @pl.when(col * page_size <= last_ref[row, tile])
+            def _page():
+                page = pt_ref[row, col]
+                dst = pl.ds(i * rows, rows)
+                for src, buf, sem in ((k_hbm, k_buf, sems.at[0, slot]),
+                                      (v_hbm, v_buf, sems.at[1, slot])):
+                    dma = pltpu.make_async_copy(src.at[page],
+                                                buf.at[slot, dst], sem)
+                    if wait:
+                        dma.wait()
+                    else:
+                        dma.start()
+
+    last = last_ref[bb, qi]
+    # block 0 is live in every tile: positions are >= 0
+    live = kj * block <= last
+
+    @pl.when(jnp.logical_and(jnp.logical_and(bb == 0, qi == 0), kj == 0))
+    def _first():
+        slot_ref[0] = 0
+        copy_block(0, 0, 0, 0, wait=False)
+
+    @pl.when(kj == 0)
+    def _init():
+        flash.init(acc_ref, m_ref, l_ref)
+        # each kv head's q group head-major, and the positions of its rows
+        for hh in range(h):
+            kh, g = divmod(hh, group)
+            q_buf[kh, pl.ds(g * block_q, block_q), :] = (
+                q_ref[:, hh, :].astype(q_buf.dtype))
+        for g in range(group):
+            qpos_ref[pl.ds(g * block_q, block_q), :] = pos_ref[...]
+
+    # each live step starts the next live block (this tile's, else the
+    # next tile's first), then waits for its own
+    @pl.when(live)
+    def _stream():
+        slot = slot_ref[0]
+        more = jnp.logical_and(kj + 1 < n_blocks, (kj + 1) * block <= last)
+
+        @pl.when(more)
+        def _next_block():
+            copy_block(bb, qi, kj + 1, 1 - slot, wait=False)
+
+        row_ends = qi + 1 == n_tiles
+        row = jnp.where(row_ends, bb + 1, bb)
+
+        @pl.when(jnp.logical_and(jnp.logical_not(more), row < n_rows))
+        def _next_tile():
+            copy_block(row, jnp.where(row_ends, 0, qi + 1), 0, 1 - slot,
+                       wait=False)
+
+        copy_block(bb, qi, kj, slot, wait=True)
+        slot_ref[0] = 1 - slot
+
+    def fold(masked: bool):
+        slot = 1 - slot_ref[0]
+        mask = v_live = None
+        if masked:
+            kpos = kj * block + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block), 1)
+            mask = kpos <= qpos_ref[...]
+            # pages after the tile's last position were not copied: their
+            # rows hold whatever the buffer held, so zero V there, lest a
+            # masked probability meet a NaN
+            v_live = (kj * block + jax.lax.broadcasted_iota(
+                jnp.int32, (block, 1), 0)) <= last
+        for kh in range(hkv):
+            v = _kv_head_rows(v_buf, slot, kh, hkv)
+            if v_live is not None:
+                v = jnp.where(v_live, v, jnp.zeros_like(v))
+            k = _kv_head_rows(k_buf, slot, kh, hkv).astype(q_buf.dtype)
+            flash.update(kh, q_buf[kh], k, v, mask, acc_ref, m_ref, l_ref,
+                         scale=sm_scale)
+
+    # a block wholly at or before the tile's least position needs no mask
+    unmasked = (kj + 1) * block - 1 <= first_ref[bb, qi]
+
+    @pl.when(jnp.logical_and(live, unmasked))
+    def _visible():
+        fold(masked=False)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_not(unmasked)))
+    def _causal():
+        fold(masked=True)
+
+    @pl.when(kj == n_blocks - 1)
+    def _finalize():
+        # out_ref is float32 whatever q's dtype, as in _finalize
+        for kh in range(hkv):
+            res = flash.result(kh, acc_ref, l_ref)   # (G * block_q, hd)
+            for g in range(group):
+                out_ref[:, kh * group + g, :] = res[g * block_q:
+                                                    (g + 1) * block_q]
+
+
+def _paged_block_call(q, k_pool, v_pool, page_table, positions, *,
+                      block_q: int = 128, interpret: bool):
+    """The multi-page paged prefill call: ``pages_per_block`` pages a grid
+    step, copied by hand from the pool in HBM (``_reads_kv_heads``)."""
+    b, s, h, hd = q.shape
+    n_pages, page_size, hkv, _ = k_pool.shape
+    group = h // hkv
+    width = page_table.shape[1]
+    ppb = pages_per_block(k_pool.shape, k_pool.dtype, width, h,
+                          block_q=block_q)
+    rows = page_size * hkv
+    tiles = positions.reshape(b, s // block_q, block_q)
+    kernel = functools.partial(_paged_block_kernel, ppb=ppb,
+                               page_size=page_size,
+                               sm_scale=1.0 / math.sqrt(hd))
+    q_spec = pl.BlockSpec((None, block_q, h, hd),
+                          lambda bb, qi, kj, pt, first, last: (bb, qi, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, s // block_q, width // ppb),
+        in_specs=[
+            q_spec,
+            pl.BlockSpec((None, block_q, 1),
+                         lambda bb, qi, kj, pt, first, last: (bb, qi, 0)),
+            pool, pool,
+        ],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((2, ppb * rows, hd), k_pool.dtype),
+                        pltpu.VMEM((2, ppb * rows, hd), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((hkv, group * block_q, hd),
+                                   jnp.promote_types(q.dtype, k_pool.dtype)),
+                        pltpu.VMEM((group * block_q, 1), jnp.int32),
+                        pltpu.VMEM((hkv, group * block_q, hd), jnp.float32),
+                        pltpu.VMEM((hkv, group * block_q, LANES),
+                                   jnp.float32),
+                        pltpu.VMEM((hkv, group * block_q, LANES),
+                                   jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        # the DMA chain runs across tiles and rows: every axis in order
+        compiler_params=flash.compiler_params("arbitrary", "arbitrary",
+                                              "arbitrary"),
+        interpret=interpret,
+        name="paged_prefill_attention",
+    )(page_table, tiles.min(axis=2), tiles.max(axis=2), q,
+      positions[:, :, None], k_pool.reshape(n_pages, rows, hd),
+      v_pool.reshape(n_pages, rows, hd)).astype(q.dtype)

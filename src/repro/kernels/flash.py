@@ -29,12 +29,18 @@ def init(acc_ref, m_ref, l_ref):
     l_ref[...] = jnp.zeros_like(l_ref)
 
 
-def update(h: int, q, k, v, mask, acc_ref, m_ref, l_ref):
-    """Fold one KV tile into head ``h``'s state: q (rows, hd) pre-scaled,
-    k/v (bk, hd), mask (rows, bk)."""
+def update(h: int, q, k, v, mask, acc_ref, m_ref, l_ref, *,
+           scale: float = 1.0):
+    """Fold one KV tile into head ``h``'s state: q (rows, hd), k/v (bk,
+    hd), mask (rows, bk) or None where every key is visible.  The f32
+    scores are multiplied by ``scale`` (1 where q comes pre-scaled); the
+    probabilities meet v in v's dtype, accumulating in f32."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    s = jnp.where(mask, s, NEG_INF)
+    if scale != 1.0:
+        s = s * scale
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
     m_prev = m_ref[h]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new[:, :1])
@@ -42,7 +48,8 @@ def update(h: int, q, k, v, mask, acc_ref, m_ref, l_ref):
     l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
     m_ref[h] = m_new
     acc_ref[h] = acc_ref[h] * alpha[:, :1] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def result(h: int, acc_ref, l_ref):

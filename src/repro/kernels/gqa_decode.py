@@ -137,21 +137,25 @@ def gqa_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                         paged=False, interpret=interpret)
 
 
-def pages_per_block(pool_shape, dtype, width: int, num_heads: int) -> int:
-    """Pages one grid step of the paged kernel streams, from the pool's
+def pages_per_block(pool_shape, dtype, width: int, num_heads: int,
+                    block_q: int | None = None) -> int:
+    """Pages one grid step of a paged kernel streams, from the pool's
     (num_pages, page_size, Hkv, hd) shape and dtype, the page-table width
     and the q head count: the largest power of two that covers at most
     ``BLOCK_KEYS`` keys, divides ``width`` and keeps the block's VMEM —
     K and V pages in two slots, their f32 copies, and the f32 scores and
-    probabilities of every q head — within half the kernel's VMEM
-    budget."""
+    probabilities — within half the kernel's VMEM budget.  In decode
+    every q head meets every row of the block; in prefill (``block_q``
+    queries a q head) each q head meets its own kv head's keys."""
     _, page_size, hkv, hd = pool_shape
     itemsize = jnp.dtype(dtype).itemsize
 
     def vmem(ppb):
         rows = ppb * page_size * hkv          # flat-view rows of a block
+        scores = (num_heads * rows if block_q is None
+                  else num_heads * block_q * ppb * page_size)
         return (2 * 2 * rows * hd * itemsize + 2 * rows * hd * 4
-                + 2 * num_heads * rows * 4)
+                + 2 * scores * 4)
 
     ppb = 1
     while (2 * ppb * page_size <= BLOCK_KEYS and width % (2 * ppb) == 0

@@ -264,7 +264,9 @@ def test_r6_computes_real_kernel_footprints():
                         rules=[VmemBudgetRule()], root=REPO_ROOT)
     assert report.findings == []
     notes = [n for n in report.notes if "VMEM footprint" in n]
-    assert len(notes) == 4   # chunked/paged prefill + dense/paged decode
+    # chunked prefill, paged prefill (one-page and multi-page calls),
+    # dense and paged decode
+    assert len(notes) == 5
     assert all("scratch" in n for n in notes)
     assert not any("+ 0 KiB scratch" in n for n in notes)
 

@@ -17,6 +17,7 @@ import pytest
 
 from _hypothesis_compat import given, settings, st
 from repro.configs import get_smoke_config
+from repro.kernels import flash
 from repro.kernels import ref as kernels_ref
 from repro.kernels.gqa_decode import pages_per_block
 from repro.kernels.ops import paged_gqa_decode, paged_prefill
@@ -341,15 +342,80 @@ def test_paged_decode_kernel_matches_ref(case):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-def test_paged_prefill_kernel_matches_ref():
+def _prefill_fixture(lens, sfx, width, *, s=256, shared=0, hkv=2, group=2,
+                     hd=128, ps=64, dtype=jnp.float32, seed=0):
+    """Rows of prompts ``lens`` tokens long on ``_block_fixture``'s pages,
+    each querying its last ``sfx`` tokens, left-padded to ``s`` queries at
+    position 0 (the engine's suffix prefill)."""
+    _, kp, vp, pt, _ = _block_fixture(lens, width, shared=shared, hkv=hkv,
+                                      group=group, hd=hd, ps=ps, seed=seed)
+    pos = np.zeros((len(lens), s), np.int32)
+    for row, (n_tok, n_q) in enumerate(zip(lens, sfx)):
+        pos[row, s - n_q:] = np.arange(n_tok - n_q, n_tok)
+    q = jax.random.normal(jax.random.PRNGKey(seed + 9),
+                          (len(lens), s, hkv * group, hd), jnp.float32)
+    return (q.astype(dtype), kp.astype(dtype), vp.astype(dtype), pt,
+            jnp.asarray(pos))
+
+
+def _hd16_one_page_prefill():
     q, kp, vp, pt, valid = _pool_fixture()
     b, s = pt.shape[0], 6
     qs = jax.random.normal(jax.random.PRNGKey(9),
                            (b, s, q.shape[1], q.shape[2]), jnp.float32)
     positions = (valid[:, None] - s + jnp.arange(s)[None, :]).clip(0)
-    out = paged_prefill(qs, kp, vp, pt, positions, block_q=8)
-    ref = kernels_ref.paged_prefill_ref(qs, kp, vp, pt, positions)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    return qs, kp, vp, pt, positions
+
+
+# each case with the pages a grid step carries (ppb) at its page-table
+# width; 16 columns of 64-slot pages at hd 128 give 8 (512 keys)
+PAGED_PREFILL_CASES = {
+    "one_page_blocks": _hd16_one_page_prefill,            # hd 16: one page
+    "narrow_head_dim_one_page_steps": lambda: _prefill_fixture(
+        [5 * 64 + 17, 130, 700], [200, 130, 256], 16, hd=64),
+    "gqa_hkv4_group8_bf16": lambda: _prefill_fixture(
+        [1000, 300, 577], [256, 300, 200], 16, s=384, hkv=4, group=8,
+        dtype=jnp.bfloat16),
+    "multi_head_group1": lambda: _prefill_fixture(
+        [900, 64, 1021], [256, 64, 100], 16, hkv=8, group=1),
+    "single_kv_head": lambda: _prefill_fixture(
+        [700, 200], [256, 200], 16, hkv=1, group=4),
+    "prompt_ends_mid_page": lambda: _prefill_fixture(
+        [5 * 64 + 17, 11 * 64 + 3, 40], [256, 90, 40], 16),
+    "prompt_ends_on_page_boundary": lambda: _prefill_fixture(
+        [3 * 64, 10 * 64, 64], [192, 256, 64], 16),
+    "prompt_ends_on_block_boundary": lambda: _prefill_fixture(
+        [512, 1024, 513], [256, 256, 1], 16),
+    # level 2: row 1's short suffix after the long prefix it shares
+    "rows_share_prefix_short_suffix": lambda: _prefill_fixture(
+        [12 * 64 + 40, 12 * 64 + 100], [256, 100], 16, shared=12, hkv=4,
+        group=8, dtype=jnp.bfloat16),
+    # first q tiles starting one key before, at and one key after the end
+    # of the first 512-key block: the block needs the mask in the first
+    "tile_starts_at_block_edge": lambda: _prefill_fixture(
+        [766, 767, 768], [256, 256, 256], 16),
+    "q_tile_of_only_left_pads": lambda: _prefill_fixture(
+        [600, 300], [100, 250], 16, s=384),
+    "width_shrinks_block_to_4": lambda: _prefill_fixture(
+        [700, 130, 768], [256, 130, 256], 12),
+    "width_shrinks_block_to_2": lambda: _prefill_fixture(
+        [200, 384], [200, 256], 6),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_PREFILL_CASES))
+def test_paged_prefill_kernel_matches_ref(case):
+    q, kp, vp, pt, positions = PAGED_PREFILL_CASES[case]()
+    block_q = 8 if q.shape[1] < 128 else 128
+    out = paged_prefill(q, kp, vp, pt, positions, block_q=block_q)
+    f32 = [a.astype(jnp.float32) for a in (q, kp, vp)]
+    ref = kernels_ref.paged_prefill_ref(*f32, pt, positions)
+    # a bfloat16 pool rounds the probabilities to bfloat16 before PV
+    # (2^-9 of the largest |v| at most) and the output to bfloat16 (half
+    # an ulp, 2^-7 below |out| 4)
+    atol = 2e-2 if q.dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref), atol=atol)
 
 
 @pytest.mark.parametrize("pool_shape, dtype, width, heads, expected", [
@@ -365,6 +431,40 @@ def test_pages_per_block_rule(pool_shape, dtype, width, heads, expected):
     ppb = pages_per_block(pool_shape, dtype, width, heads)
     assert ppb == expected
     assert ppb <= width and width % ppb == 0
+
+
+@pytest.mark.parametrize("pool_shape, dtype, width, heads, expected", [
+    ((640, 64, 4, 128), jnp.bfloat16, 64, 32, 8),   # yi-6b prefill
+    ((640, 64, 4, 128), jnp.bfloat16, 12, 32, 4),   # the width caps it
+    ((92, 64, 32, 128), jnp.bfloat16, 64, 32, 4),   # multi-head: VMEM
+    ((16, 64, 64, 256), jnp.float32, 64, 64, 1),
+])
+def test_pages_per_block_prefill_rule(pool_shape, dtype, width, heads,
+                                      expected):
+    """The prefill call's block: each q head's 128 queries meet its own kv
+    head's keys, so the scores grow with the q heads and block_q."""
+    assert pages_per_block(pool_shape, dtype, width, heads,
+                           block_q=128) == expected
+
+
+def test_paged_prefill_block_fits_vmem_at_level_one():
+    """At yi-6b's level-1 shape (4 rows of 4096 queries, 32 q heads over
+    4 kv heads of 128, a 64-column page table of 64-slot bfloat16 pages)
+    a grid step streams 8 pages, and everything it holds in VMEM — the
+    double-buffered q, positions and f32 out blocks, the K/V block slots,
+    the laid-out q groups and their positions, the softmax state, and one
+    kv head's f32 scores and probabilities — fits the kernel's limit."""
+    b, s, h, hkv, hd, ps, width, block_q = 4, 4096, 32, 4, 128, 64, 64, 128
+    ppb = pages_per_block((640, ps, hkv, hd), jnp.bfloat16, width, h,
+                          block_q=block_q)
+    assert ppb == 8 and (b, s // block_q, width // ppb) == (4, 32, 8)
+    group, keys = h // hkv, ppb * ps
+    blocks = 2 * (block_q * h * hd * 2 + block_q * 4 + block_q * h * hd * 4)
+    kv_slots = 2 * 2 * ppb * ps * hkv * hd * 2
+    state = (hkv * group * block_q * (hd * 2 + hd * 4 + 2 * 128 * 4)
+             + group * block_q * 128 * 4)
+    scores = 2 * group * block_q * keys * 4
+    assert blocks + kv_slots + state + scores <= flash.VMEM_LIMIT_BYTES // 2
 
 
 def test_pages_per_block_never_exceeds_width():
